@@ -71,6 +71,9 @@ def run(quick: bool = True, rank: int = 64):
         "%RANK%", str(rank))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # The child only compiles for virtual CPU devices; the parent may hold
+    # the accelerator, which a second process must not try to take.
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", script], env=env,
                           capture_output=True, text=True, timeout=1800)
     rows = []

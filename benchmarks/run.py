@@ -9,12 +9,14 @@ through ``common.write_bench_json`` — the single naming authority —
 as ``experiments/bench/BENCH_<suite>.json`` (the pre-PR-5 lowercase
 ``<suite>.json`` dumps are retired). A suite that *errors* still gets a
 BENCH file recording the failure, so downstream tooling can glob
-``BENCH_*.json`` and see every suite accounted for.
+``BENCH_*.json`` and see every suite accounted for — and the driver
+then exits nonzero.
 """
 from __future__ import annotations
 
 import argparse
 import os
+import sys
 import time
 
 from . import (bench_bf16_convergence, bench_collective_traffic,
@@ -25,6 +27,7 @@ from . import (bench_bf16_convergence, bench_collective_traffic,
                bench_schedule, bench_total_time, roofline)
 from . import common
 from .common import print_rows, write_bench_json
+from repro.runtime.compile_cache import enable_compile_cache
 
 SUITES = {
     "remap_fusion": bench_remap_fusion.run,      # Fig. 2
@@ -47,7 +50,7 @@ SUITES = {
 }
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None,
@@ -58,7 +61,9 @@ def main() -> None:
     names = list(SUITES) if not args.only else args.only.split(",")
     os.makedirs(args.out, exist_ok=True)
     common.BENCH_OUT_DIR = args.out     # BENCH_*.json follow --out
+    enable_compile_cache()
     all_rows = []
+    errored = []
     for name in names:
         fn = SUITES[name]
         t0 = time.perf_counter()
@@ -67,12 +72,18 @@ def main() -> None:
         except Exception as e:                    # noqa: BLE001
             rows = [dict(bench=name, status="error", error=repr(e)[:200])]
             write_bench_json(name, rows)
+        if any(r.get("status") == "error" for r in rows):
+            errored.append(name)
         dt = time.perf_counter() - t0
         print(f"## {name} ({dt:.1f}s)", flush=True)
         print_rows(rows)
         all_rows.extend(rows)
     print(f"## done: {len(all_rows)} rows -> {args.out}/", flush=True)
+    if errored:
+        print(f"## errored suites: {','.join(errored)}", file=sys.stderr)
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

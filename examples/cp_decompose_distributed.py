@@ -21,10 +21,12 @@ from repro.core import distributed as dist
 from repro.core.cpals import cp_als_distributed
 from repro.core.flycoo import build_flycoo
 from repro.core.tensors import SparseTensor, frostt_like
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
-    print("=== distributed Dynasor CP-ALS (8 workers) ===")
+    D = len(jax.devices())
+    print(f"=== distributed Dynasor CP-ALS ({D} workers) ===")
     mesh = Mesh(np.array(jax.devices()), (dist.AXIS,))
 
     # exact recovery of a dense rank-4 tensor
@@ -34,7 +36,7 @@ def main():
     dense = np.einsum("ir,jr,kr->ijk", *facs)
     idx = np.array(list(itertools.product(*map(range, shape))), np.int32)
     t = SparseTensor(idx, dense.reshape(-1).astype(np.float32), shape)
-    ft = build_flycoo(t, 8, m_bounds=(2, 8), g_bounds=(8, 64))
+    ft = build_flycoo(t, D, m_bounds=(2, 8), g_bounds=(8, 64))
     res = cp_als_distributed(ft, R, mesh, iters=20, seed=1)
     rec = np.einsum("r,ir,jr,kr->ijk", res.lam, *res.factors)
     rel = np.linalg.norm(rec - dense) / np.linalg.norm(dense)
@@ -50,7 +52,7 @@ def main():
     dense4 = np.einsum("ir,jr,kr,lr->ijkl", *facs4)
     idx4 = np.array(list(itertools.product(*map(range, shape4))), np.int32)
     t4 = SparseTensor(idx4, dense4.reshape(-1).astype(np.float32), shape4)
-    ft4 = build_flycoo(t4, 8, m_bounds=(2, 8), g_bounds=(8, 64),
+    ft4 = build_flycoo(t4, D, m_bounds=(2, 8), g_bounds=(8, 64),
                        fused_gather=True)
     res4 = cp_als_distributed(ft4, R4, mesh, iters=15, seed=1,
                               backend="auto")
@@ -61,7 +63,7 @@ def main():
 
     # Dynasor vs nonzero-parallel all-reduce baseline on a FROSTT profile
     t2 = frostt_like("nell-2", scale=0.15)
-    ft2 = build_flycoo(t2, 8)
+    ft2 = build_flycoo(t2, D)
     rt, (pidx, pval, pmask) = dist.prepare_runtime(ft2, rank=16)
     factors = dist.init_factors(ft2, rt, seed=0)
     dynasor = dist.make_spmttkrp_all_modes(rt, mesh, backend="segsum")
@@ -78,7 +80,7 @@ def main():
             jax.block_until_ready(fn(*args, *factors))
         dt = (time.perf_counter() - t0) / 3
         print(f"{name:20s} all-modes spMTTKRP: {dt * 1e3:.1f} ms "
-              f"(nnz={t2.nnz}, R=16, 8 workers)")
+              f"(nnz={t2.nnz}, R=16, {D} workers)")
     print("note: on emulated same-host devices collectives are ~free, so "
           "the all-reduce baseline wins wall-clock at toy scale; the "
           "compiled collective-byte comparison (benchmarks/"
@@ -88,4 +90,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

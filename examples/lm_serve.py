@@ -13,6 +13,7 @@ from repro.configs import smoke_config
 from repro.launch.serve import ServeSession
 from repro.models import model as M
 from repro.models.params import init_params
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -40,4 +41,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

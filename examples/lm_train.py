@@ -13,6 +13,7 @@ import shutil
 import tempfile
 
 from repro.launch.train import train
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -46,4 +47,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

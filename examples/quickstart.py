@@ -17,6 +17,7 @@ from repro.core.flycoo import build_flycoo, choose_partition_params
 from repro.core.tensors import frostt_like, low_rank_sparse_tensor
 from repro.kernels.mttkrp import ops as kops
 from repro import tune
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def main():
@@ -84,4 +85,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

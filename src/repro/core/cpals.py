@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from ..compat import shard_map as _shard_map
 from ..obs import counters as _obs
 from ..obs import tracer as _tracer
 from ..resilience import checkpoint as _ckpt
@@ -246,12 +245,13 @@ def make_als_sweep(rt: dist.DynasorRuntime, mesh: Mesh, *,
 
     from jax.sharding import PartitionSpec as P
     spec_t, spec_r = P(dist.AXIS), P()
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(spec_t, spec_t, spec_t, spec_t)
         + (spec_r,) * (rt.nmodes + 2),
         out_specs=((spec_t, spec_t, spec_t), [spec_r] * rt.nmodes, spec_r,
                    spec_r),
+        check_vma=False,
     )
     return jax.jit(shmapped)
 
@@ -290,20 +290,20 @@ def make_instrumented_mode_fns(rt: dist.DynasorRuntime, mesh: Mesh, *,
             idx, val, mask = idx[0], val[0], mask[0]
             return dist.device_mttkrp(idx, val, mask, list(factors), _n,
                                       rt, backend)
-        mttkrp_fns.append(jax.jit(_shard_map(
+        mttkrp_fns.append(jax.jit(jax.shard_map(
             mttkrp_inner, mesh=mesh,
             in_specs=(spec_t, spec_t, spec_t) + (spec_r,) * rt.nmodes,
-            out_specs=spec_t)))
+            out_specs=spec_t, check_vma=False)))
 
         def remap_inner(idx, val, mask, _n=n):
             idx, val, mask = idx[0], val[0], mask[0]
             idx, val, mask, _ = dist.device_remap(
                 idx, val, mask, (_n + 1) % rt.nmodes, rt)
             return idx[None], val[None], mask[None]
-        remap_fns.append(jax.jit(_shard_map(
+        remap_fns.append(jax.jit(jax.shard_map(
             remap_inner, mesh=mesh,
             in_specs=(spec_t, spec_t, spec_t),
-            out_specs=(spec_t, spec_t, spec_t))))
+            out_specs=(spec_t, spec_t, spec_t), check_vma=False)))
     return mttkrp_fns, remap_fns
 
 
@@ -458,6 +458,11 @@ def cp_als_distributed(ft: FlycooTensor, rank: int, mesh: Mesh, *,
     a recorded walk down the residency ladder — every fallback counted
     in the ``resilience.*`` namespace, never a silent wrong answer.
     """
+    if ft.params.num_workers != mesh.shape[dist.AXIS]:
+        raise ValueError(
+            f"tensor was built for {ft.params.num_workers} workers but the "
+            f"mesh has {mesh.shape[dist.AXIS]} devices on {dist.AXIS!r}: "
+            "build_flycoo(t, num_workers=<mesh size>)")
     tracer = _tracer.get_tracer() if tracer is None else tracer
     rt, (idx, val, mask) = dist.prepare_runtime(ft, rank,
                                                 tile_rows=tile_rows,

@@ -41,12 +41,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map as _shard_map
 from . import remap as remap_lib
 from .flycoo import FlycooTensor, pack_mode
 from ..kernels.mttkrp import ops as kops
 from ..obs import counters as _obs
 from ..resilience import faults as _faults
+from ..runtime import execution as _execution
 
 __all__ = [
     "AXIS",
@@ -210,7 +210,8 @@ def prepare_runtime(
         for mp, t in zip(ft.modes, tiles)
     )
     i_pad = tuple(D * rc for rc in rows_cap)
-    blk = int(blk if blk is not None else min(ft.params.g, 512))
+    if blk is None:
+        blk = kops.runtime_blk(ft.params.g, compiled=_execution.compiles())
     caps = remap_lib.remap_capacities(ft)
     # Count the per-transition all_to_all allocation here, once per
     # runtime build — every driver (CP-ALS, benches, serving) that
@@ -279,13 +280,19 @@ def unpermute_factor(ft: FlycooTensor, rt: DynasorRuntime, mode: int,
 # ---------------------------------------------------------------------------
 
 def _pack_payload(idx, val):
-    bits = jax.lax.bitcast_convert_type(idx.astype(jnp.int32), jnp.float32)
-    return jnp.concatenate([bits, val[:, None].astype(jnp.float32)], axis=1)
+    """Coordinates + value as one int32 row per nonzero.
+
+    The value travels as its fp32 bit pattern. The reverse (coordinates
+    as fp32 bits) would turn small indices into denormals, which the TPU
+    flushes to zero when the exchange buffers are written.
+    """
+    bits = jax.lax.bitcast_convert_type(val.astype(jnp.float32), jnp.int32)
+    return jnp.concatenate([idx.astype(jnp.int32), bits[:, None]], axis=1)
 
 
 def _unpack_payload(payload, nmodes):
-    idx = jax.lax.bitcast_convert_type(payload[:, :nmodes], jnp.int32)
-    return idx, payload[:, nmodes]
+    val = jax.lax.bitcast_convert_type(payload[:, nmodes], jnp.float32)
+    return payload[:, :nmodes], val
 
 
 def device_mttkrp(idx, val, mask, factors, mode: int, rt: DynasorRuntime,
@@ -419,11 +426,12 @@ def make_spmttkrp_all_modes(
 
     spec_t = P(AXIS)
     spec_r = P()
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(spec_t, spec_t, spec_t) + (spec_r,) * rt.nmodes,
         out_specs=([spec_r] * rt.nmodes, (spec_t, spec_t, spec_t),
                    {"dropped": spec_r}),
+        check_vma=False,
     )
     return jax.jit(shmapped)
 
@@ -447,10 +455,11 @@ def make_baseline_all_modes(rt: DynasorRuntime, mesh: Mesh) -> Callable:
 
     spec_t = P(AXIS)
     spec_r = P()
-    shmapped = _shard_map(
+    shmapped = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(spec_t, spec_t, spec_t) + (spec_r,) * rt.nmodes,
         out_specs=[spec_r] * rt.nmodes,
+        check_vma=False,
     )
     return jax.jit(shmapped)
 

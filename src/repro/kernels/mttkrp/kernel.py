@@ -42,7 +42,7 @@ Kernel matrix (see ops.py for the dispatch layer that picks between them):
   ``fused_mttkrp_nmode_gather``  gather **inside the kernel**: takes the
                               full replicated factor matrices (VMEM-resident
                               across grid steps) plus a block-aligned
-                              ``(n_pad, N−1)`` int32 index stream, and forms
+                              ``(N−1, n_pad)`` int32 index stream, and forms
                               each nonzero's factor rows in the body
                               (one-hot MXU matmul when compiled — the
                               ``gather`` primitive has no Mosaic lowering —
@@ -78,9 +78,11 @@ product is accumulated in fp32 inside the kernel regardless, so bf16 only
 halves the *gathered-operand* footprint and HBM gather traffic.
 
 Grid: one step per nonzero block. ``tile_of_block`` is scalar-prefetched and
-drives the output BlockSpec index_map. The output is zero-initialized via
-``input_output_aliases`` (an aliased zeros operand), so empty tiles stay
-zero without needing a first-visit flag.
+drives the output BlockSpec index_map. The output aliases an ``out_init``
+operand (zeros by default) via ``input_output_aliases``: tiles no block
+visits keep it, and the first block of each tile's run copies its init
+tile into the VMEM accumulator (a compiled kernel never reads output
+blocks back from HBM — see ``_accumulate``).
 
 Execution mode: every entry point takes ``interpret: bool | None``.
 ``None`` — the default everywhere — resolves through
@@ -88,9 +90,12 @@ Execution mode: every entry point takes ``interpret: bool | None``.
 interpret / compiled / auto policy with capability probing; a bool is an
 explicit per-call override (the lowering harness passes ``False``).
 Compiled (Mosaic) geometry constraint: the rank-1 ``(blk,)`` scalar-stream
-blocks require ``blk`` to be a multiple of 128 (the interpreter accepts
-any ``blk``); ``tests/test_lowering.py`` lowers every kernel wrapper with
-``interpret=False`` to keep the compiled path honest on CPU-only hosts.
+blocks require ``blk`` to be a multiple of ``COMPILED_BLK_MULTIPLE`` (1024;
+the interpreter accepts any ``blk``), and every ``pallas_call`` asks for
+``VMEM_LIMIT_BYTES`` of scoped VMEM. ``tests/test_lowering.py`` lowers
+every kernel wrapper with ``interpret=False`` and
+``tests/test_tpu_compile.py`` compiles the main path for a described
+v5e, to keep the compiled path honest on CPU-only hosts.
 """
 from __future__ import annotations
 
@@ -148,6 +153,28 @@ MIN_MXU_RANK = MXU_RANK_MULTIPLE // 16
 # v5e core's ~128 MiB VMEM — same θ=0.5 cache-fraction stance as the
 # paper's Eq. 3). Same single-source rationale as MIN_MXU_RANK.
 VMEM_BUDGET_BYTES = 64 * 1024 * 1024
+
+# Scoped-VMEM limit every kernel asks Mosaic for. Pallas double-buffers
+# each blocked operand, so a step whose working set the planner
+# certified under VMEM_BUDGET_BYTES may need up to twice that; the
+# compiler's own default limit (far below the budget) would otherwise
+# decide what compiles.
+VMEM_LIMIT_BYTES = 2 * VMEM_BUDGET_BYTES - 16 * 1024 * 1024
+
+# Scalar-prefetch (SMEM) budget for residency planning: half of a v5e
+# core's 1 MiB SMEM. Every scalar-prefetched operand (``tile_of_block``,
+# the stream kernel's tile schedules) is held whole in SMEM.
+SMEM_BUDGET_BYTES = 512 * 1024
+
+# Compiled-path block rule: XLA lays rank-1 int32/fp32 arrays out in
+# 1024-element tiles on TPU, and Mosaic refuses a (blk,) block whose
+# tiling does not match, so compiled kernels need ``blk % 1024 == 0``.
+# The interpreter accepts any ``blk``.
+COMPILED_BLK_MULTIPLE = 1024
+
+# Factor rows one step of the compiled one-hot gather covers (see
+# ``_gather_rows``).
+GATHER_CHUNK_ROWS = 512
 
 # Dispatch-level name of the out-of-core streaming kernel
 # (fused_mttkrp_nmode_gather_stream) in ops.BACKENDS.
@@ -273,18 +300,62 @@ def gather_stream_vmem_bytes(num_in_modes: int, rank_padded: int, blk: int,
         index_stream_modes=num_in_modes)
 
 
+def _split_bf16(x):
+    """fp32 ``x`` as ``(hi, mid, lo)`` bf16 parts with ``(hi+mid)+lo == x``.
+
+    Each part keeps the next 8 significant bits of the remainder, so
+    three cover fp32's 24. Non-fp32 input (bf16 factors) is one part.
+    """
+    if x.dtype != jnp.float32:
+        return (x,)
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _onehot_dot(onehot, x, dimension_numbers):
+    """``onehot ⋅ x`` on the MXU with ``x`` carried at full fp32 precision.
+
+    At the MXU's default precision an fp32 operand is rounded to bf16.
+    A 0/1 ``onehot`` is exact in bf16, so ``x`` is split into three bf16
+    parts (:func:`_split_bf16`) and each part's product — exact, fp32
+    accumulate — is summed: every output is then the fp32 sum of the
+    selected ``x`` entries, without Mosaic's much slower fp32 matmul.
+    """
+    parts = _split_bf16(x)
+    out = None
+    for part in parts:
+        d = jax.lax.dot_general(
+            onehot.astype(part.dtype), part,
+            dimension_numbers=dimension_numbers,
+            preferred_element_type=jnp.float32)
+        out = d if out is None else out + d
+    return out
+
+
 def _scatter_update(rows, contrib, tile_rows: int):
     """One-hot MXU scatter: ``(T×B) @ (B×R)`` update for the output tile."""
     iota = jax.lax.broadcasted_iota(jnp.int32, (rows.shape[0], tile_rows), 1)
-    onehot = (rows[:, None] == iota).astype(contrib.dtype)
-    return jax.lax.dot_general(
-        onehot, contrib,
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    onehot = rows[:, None] == iota
+    return _onehot_dot(onehot, contrib, (((0,), (0,)), ((), ())))
 
 
-def _gather_rows(matrix, idx, *, onehot: bool):
+def _onehot_rows(matrix, idx, base):
+    """``onehot(idx - base) @ matrix``: rows ``base …`` of one chunk.
+
+    The one-hot is built transposed, ``(I×B)``, so the lane-major index
+    row broadcasts along sublanes with no relayout, and the matmul
+    contracts its leading axis.
+    """
+    iota = jax.lax.broadcasted_iota(
+        jnp.int32, (matrix.shape[0], idx.shape[0]), 0) + base
+    return _onehot_dot(iota == idx[None, :], matrix,
+                       (((0,), (0,)), ((), ())))
+
+
+def _gather_rows(matrix, idx, *, onehot: bool, chunk_rows: int | None = None):
     """In-kernel row gather: ``out[i] = matrix[idx[i]]`` → ``(B, R)`` fp32.
 
     Two bitwise-identical implementations behind one switch:
@@ -295,6 +366,9 @@ def _gather_rows(matrix, idx, *, onehot: bool):
       * ``onehot=True`` — the MXU form, the gather mirror of
         :func:`_scatter_update`: ``onehot(idx, I) (B×I) @ matrix (I×R)``
         on the systolic array. This is what the compiled path uses.
+        With ``chunk_rows``, ``matrix`` is a VMEM ref read
+        ``chunk_rows`` rows at a time in a loop, so the one-hot block and
+        the kernel's VMEM stay bounded however tall the factor is.
 
     Equivalence is exact for in-range indices and any finite data: each
     output row is ``1.0·matrix[idx[i]]`` plus exact ``+0.0`` terms, and a
@@ -304,18 +378,27 @@ def _gather_rows(matrix, idx, *, onehot: bool):
     tests/test_lowering.py locks the equivalence down.
     """
     if not onehot:
-        return jnp.take(matrix, idx, axis=0)
-    iota = jax.lax.broadcasted_iota(
-        jnp.int32, (idx.shape[0], matrix.shape[0]), 1)
-    sel = (idx[:, None] == iota).astype(matrix.dtype)
-    return jax.lax.dot_general(
-        sel, matrix,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+        return jnp.take(matrix[...], idx, axis=0)
+    rows = matrix.shape[0]
+    if chunk_rows is None or rows <= chunk_rows:
+        return _onehot_rows(matrix[...], idx, 0)
+    full = rows // chunk_rows
+
+    def body(c, acc):
+        base = pl.multiple_of(c * chunk_rows, chunk_rows)
+        part = matrix[pl.ds(base, chunk_rows), :]
+        return acc + _onehot_rows(part, idx, base)
+
+    acc = jax.lax.fori_loop(
+        0, full, body,
+        jnp.zeros((idx.shape[0], matrix.shape[1]), jnp.float32))
+    if rows % chunk_rows:
+        base = full * chunk_rows
+        acc = acc + _onehot_rows(matrix[base:, :], idx, base)
+    return acc
 
 
-def _resolve_interpret(interpret):
+def _resolve_interpret(interpret, blk: int):
     """Resolve a per-call ``interpret`` override against the session policy.
 
     ``None`` — the default on every kernel entry point — defers to
@@ -325,11 +408,25 @@ def _resolve_interpret(interpret):
     lazily so this module keeps its no-import-time-intra-repo-deps
     property (ops.py and the oocore planner both alias its constants and
     may be imported in either order).
+
+    A compiled call with a ``blk`` Mosaic would refuse raises here, with
+    the rule, instead of deep inside the compiler.
     """
-    if interpret is not None:
-        return bool(interpret)
-    from ...runtime import execution as _execution
-    return _execution.default_interpret()
+    if interpret is None:
+        from ...runtime import execution as _execution
+        interpret = _execution.default_interpret()
+    interpret = bool(interpret)
+    if not interpret and blk % COMPILED_BLK_MULTIPLE:
+        raise ValueError(
+            f"blk={blk} is not a multiple of {COMPILED_BLK_MULTIPLE}: "
+            "compiled (Mosaic) kernels need rank-1 (blk,) blocks that "
+            "match XLA's 1024-element tiling")
+    return interpret
+
+
+def _compiler_params():
+    """Mosaic options shared by every kernel (ignored when interpreting)."""
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 @functools.partial(
@@ -360,7 +457,7 @@ def segment_accumulate(
     Returns:
       ``(rows_cap, R)`` float32 accumulated output.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = _resolve_interpret(interpret, blk)
     n_pad, rank = contrib.shape
     assert n_pad % blk == 0, (n_pad, blk)
     assert rows_cap % tile_rows == 0, (rows_cap, tile_rows)
@@ -385,18 +482,38 @@ def segment_accumulate(
         out_shape=jax.ShapeDtypeStruct((rows_cap, rank), jnp.float32),
         input_output_aliases={3: 0},        # out_init -> out (indices incl. prefetch)
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(tile_of_block, local_row_in_tile, contrib, out_init)
+
+
+def _accumulate(tile_ref, init_ref, out_ref, update, *, block_axis: int):
+    """``out tile += update``, starting each tile's run of blocks from init.
+
+    A compiled kernel never reads its output blocks back from HBM: the
+    VMEM output buffer holds whatever the pipeline last left there. So
+    the first block of each output tile's (contiguous, FLYCOO-sorted)
+    run copies the aliased ``out_init`` tile in before accumulating —
+    zeros, or the running accumulator of a chunked executor. Tiles no
+    block visits keep the aliased content untouched.
+    """
+    b = pl.program_id(block_axis)
+    first = (b == 0) | (tile_ref[b] != tile_ref[jnp.maximum(b - 1, 0)])
+
+    @pl.when(first)
+    def _():
+        out_ref[...] = init_ref[...]
+
+    out_ref[...] += update.astype(out_ref.dtype)
 
 
 def _accum_body_aliased(tile_ref, row_ref, contrib_ref, init_ref, out_ref,
                         *, tile_rows: int):
-    """Aliased variant: out_ref starts as the (zeros) alias content."""
-    del tile_ref, init_ref
+    """Scatter-only body: accumulate one materialized ``contrib`` block."""
     update = _scatter_update(row_ref[...], contrib_ref[...], tile_rows)
-    out_ref[...] += update.astype(out_ref.dtype)
+    _accumulate(tile_ref, init_ref, out_ref, update, block_axis=0)
 
 
-def _fused_nmode_body(*refs, tile_rows: int):
+def _fused_nmode_body(*refs, tile_rows: int, block_axis: int):
     """Fused Hadamard (Alg. 2 lines 19-23) + scatter, any tensor order.
 
     Ref layout (positional, after scalar prefetch): ``tile_ref, row_ref,
@@ -414,13 +531,12 @@ def _fused_nmode_body(*refs, tile_rows: int):
     tile_ref, row_ref, val_ref = refs[0], refs[1], refs[2]
     factor_refs = refs[3:-2]
     init_ref, out_ref = refs[-2], refs[-1]
-    del tile_ref, init_ref
     rows = row_ref[...]
     contrib = val_ref[...][:, None].astype(jnp.float32)
     for rows_w in factor_refs:
         contrib = contrib * rows_w[...]
     update = _scatter_update(rows, contrib, tile_rows)
-    out_ref[...] += update.astype(out_ref.dtype)
+    _accumulate(tile_ref, init_ref, out_ref, update, block_axis=block_axis)
 
 
 @functools.partial(
@@ -464,7 +580,7 @@ def fused_mttkrp_nmode(
     Returns:
       ``(rows_cap, R)`` float32 accumulated output.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = _resolve_interpret(interpret, blk)
     factor_rows = tuple(factor_rows)
     assert factor_rows, "need at least one input-factor operand"
     n_pad, rank = factor_rows[0].shape
@@ -499,12 +615,14 @@ def fused_mttkrp_nmode(
     if out_init is None:
         out_init = jnp.zeros((rows_cap, rank), dtype=jnp.float32)
     return pl.pallas_call(
-        functools.partial(_fused_nmode_body, tile_rows=tile_rows),
+        functools.partial(_fused_nmode_body, tile_rows=tile_rows,
+                          block_axis=0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_cap, rank), jnp.float32),
         # out_init -> out; operand index counts prefetch + row/val + factors.
         input_output_aliases={3 + n_in: 0},
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(tile_of_block, local_row_in_tile, vals, *factor_rows, out_init)
 
 
@@ -546,7 +664,7 @@ def fused_mttkrp_nmode_tiled(
     slab — ``2·4 B`` per nonzero per slab, negligible against the
     ``(N−1)·R·4 B`` gather traffic each slab pass moves anyway.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = _resolve_interpret(interpret, blk)
     factor_rows = tuple(factor_rows)
     assert factor_rows, "need at least one input-factor operand"
     n_pad, rank = factor_rows[0].shape
@@ -584,16 +702,19 @@ def fused_mttkrp_nmode_tiled(
     if out_init is None:
         out_init = jnp.zeros((rows_cap, rank), dtype=jnp.float32)
     return pl.pallas_call(
-        functools.partial(_fused_nmode_body, tile_rows=tile_rows),
+        functools.partial(_fused_nmode_body, tile_rows=tile_rows,
+                          block_axis=1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_cap, rank), jnp.float32),
         # out_init -> out; operand index counts prefetch + row/val + factors.
         input_output_aliases={3 + n_in: 0},
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(tile_of_block, local_row_in_tile, vals, *factor_rows, out_init)
 
 
-def _fused_gather_body(*refs, tile_rows: int, onehot_gather: bool):
+def _fused_gather_body(*refs, tile_rows: int, onehot_gather: bool,
+                       block_axis: int):
     """In-kernel gather + Hadamard + scatter (Alg. 2 lines 13-25 whole).
 
     Ref layout (positional, after scalar prefetch): ``tile_ref, row_ref,
@@ -615,15 +736,15 @@ def _fused_gather_body(*refs, tile_rows: int, onehot_gather: bool):
     tile_ref, row_ref, val_ref, idx_ref = refs[0], refs[1], refs[2], refs[3]
     factor_refs = refs[4:-2]
     init_ref, out_ref = refs[-2], refs[-1]
-    del tile_ref, init_ref
     rows = row_ref[...]
     idx = idx_ref[...]
     contrib = val_ref[...][:, None].astype(jnp.float32)
     for w, f_ref in enumerate(factor_refs):
-        contrib = contrib * _gather_rows(f_ref[...], idx[:, w],
-                                         onehot=onehot_gather)
+        contrib = contrib * _gather_rows(f_ref, idx[w],
+                                         onehot=onehot_gather,
+                                         chunk_rows=GATHER_CHUNK_ROWS)
     update = _scatter_update(rows, contrib, tile_rows)
-    out_ref[...] += update.astype(out_ref.dtype)
+    _accumulate(tile_ref, init_ref, out_ref, update, block_axis=block_axis)
 
 
 @functools.partial(
@@ -654,10 +775,12 @@ def fused_mttkrp_nmode_gather(
 
     Args:
       vals: ``(num_blocks*blk,)`` block-aligned nonzero values; padding 0.
-      idx_stream: ``(num_blocks*blk, K)`` int32, K = N−1 input modes —
+      idx_stream: ``(K, num_blocks*blk)`` int32, K = N−1 input modes —
         the factor row index of each nonzero per input mode, in the same
         order as ``factors``; padding slots point at row 0 (harmless:
-        their value is 0).
+        their value is 0). Mode-major, so each mode's stream is
+        lane-dense: a ``(n, K)`` int32 array would be padded to 128
+        lanes on TPU, 512 B per slot.
       factors: tuple/list of K ``(I_pad_w, R)`` replicated input-factor
         matrices (the output mode's factor is *not* passed). R identical
         across operands, a multiple of ``MXU_RANK_MULTIPLE`` (ops.py
@@ -670,10 +793,10 @@ def fused_mttkrp_nmode_gather(
     Returns:
       ``(rows_cap, R)`` float32 accumulated output.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = _resolve_interpret(interpret, blk)
     factors = tuple(factors)
     assert factors, "need at least one input-factor matrix"
-    n_pad, n_in = idx_stream.shape
+    n_in, n_pad = idx_stream.shape
     assert n_in == len(factors), (n_in, len(factors))
     rank = factors[0].shape[1]
     for f in factors:
@@ -686,7 +809,7 @@ def fused_mttkrp_nmode_gather(
         [
             pl.BlockSpec((blk,), lambda b, tiles: (b,)),           # local_row
             pl.BlockSpec((blk,), lambda b, tiles: (b,)),           # vals
-            pl.BlockSpec((blk, n_in), lambda b, tiles: (b, 0)),    # idx stream
+            pl.BlockSpec((n_in, blk), lambda b, tiles: (0, b)),    # idx stream
         ]
         + [
             # Whole replicated factor matrix, block index pinned at the
@@ -710,13 +833,14 @@ def fused_mttkrp_nmode_gather(
         out_init = jnp.zeros((rows_cap, rank), dtype=jnp.float32)
     return pl.pallas_call(
         functools.partial(_fused_gather_body, tile_rows=tile_rows,
-                          onehot_gather=not interpret),
+                          onehot_gather=not interpret, block_axis=0),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_cap, rank), jnp.float32),
         # out_init -> out; operand index counts prefetch + row/val/idx +
         # the K factor matrices.
         input_output_aliases={4 + n_in: 0},
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(tile_of_block, local_row_in_tile, vals, idx_stream, *factors, out_init)
 
 
@@ -756,10 +880,10 @@ def fused_mttkrp_nmode_gather_tiled(
     once per slab (``(2+K)·4`` B per nonzero per slab), still a factor
     ``R̂/rank_slab`` smaller than streaming pre-gathered rows.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = _resolve_interpret(interpret, blk)
     factors = tuple(factors)
     assert factors, "need at least one input-factor matrix"
-    n_pad, n_in = idx_stream.shape
+    n_in, n_pad = idx_stream.shape
     assert n_in == len(factors), (n_in, len(factors))
     rank = factors[0].shape[1]
     for f in factors:
@@ -774,7 +898,7 @@ def fused_mttkrp_nmode_gather_tiled(
         [
             pl.BlockSpec((blk,), lambda s, b, tiles: (b,)),        # local_row
             pl.BlockSpec((blk,), lambda s, b, tiles: (b,)),        # vals
-            pl.BlockSpec((blk, n_in), lambda s, b, tiles: (b, 0)),  # idx
+            pl.BlockSpec((n_in, blk), lambda s, b, tiles: (0, b)),  # idx
         ]
         + [
             # One rank slab of the whole factor matrix per slab pass.
@@ -798,13 +922,14 @@ def fused_mttkrp_nmode_gather_tiled(
         out_init = jnp.zeros((rows_cap, rank), dtype=jnp.float32)
     return pl.pallas_call(
         functools.partial(_fused_gather_body, tile_rows=tile_rows,
-                          onehot_gather=not interpret),
+                          onehot_gather=not interpret, block_axis=1),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows_cap, rank), jnp.float32),
         # out_init -> out; operand index counts prefetch + row/val/idx +
         # the K factor matrices.
         input_output_aliases={4 + n_in: 0},
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(tile_of_block, local_row_in_tile, vals, idx_stream, *factors, out_init)
 
 
@@ -838,7 +963,7 @@ def _fused_gather_stream_body(*refs, tile_rows: int, num_in_modes: int,
     sched_refs = refs[1:1 + k]
     row_ref, val_ref, idx_ref = refs[1 + k], refs[2 + k], refs[3 + k]
     win_refs = refs[4 + k:-2]
-    out_ref = refs[-1]
+    init_ref, out_ref = refs[-2], refs[-1]
     b = pl.program_id(1)                     # grid = (slabs, blocks)
     rows = row_ref[...]
     idx = idx_ref[...]
@@ -849,15 +974,15 @@ def _fused_gather_stream_body(*refs, tile_rows: int, num_in_modes: int,
         slots = [win_refs[off + j][...] for j in range(width)]
         off += width
         window = slots[0] if width == 1 else jnp.concatenate(slots, axis=0)
-        gtile = (idx[:, w] // frow_tile).astype(jnp.int32)
+        gtile = (idx[w] // frow_tile).astype(jnp.int32)
         slot = jnp.zeros_like(gtile)
         for j in range(width - 1, -1, -1):
             slot = jnp.where(gtile == sched_refs[w][b, j], j, slot)
-        local = slot * frow_tile + idx[:, w] % frow_tile
+        local = slot * frow_tile + idx[w] % frow_tile
         contrib = contrib * _gather_rows(window, local,
                                          onehot=onehot_gather)
     update = _scatter_update(rows, contrib, tile_rows)
-    out_ref[...] += update.astype(out_ref.dtype)
+    _accumulate(refs[0], init_ref, out_ref, update, block_axis=1)
 
 
 @functools.partial(
@@ -914,11 +1039,11 @@ def fused_mttkrp_nmode_gather_stream(
 
     Returns ``(rows_cap, R)`` float32 accumulated output.
     """
-    interpret = _resolve_interpret(interpret)
+    interpret = _resolve_interpret(interpret, blk)
     factors = tuple(factors)
     tile_schedules = tuple(tile_schedules)
     assert factors, "need at least one input-factor matrix"
-    n_pad, n_in = idx_stream.shape
+    n_in, n_pad = idx_stream.shape
     assert n_in == len(factors) == len(tile_schedules), (
         n_in, len(factors), len(tile_schedules))
     rank = factors[0].shape[1]
@@ -938,8 +1063,8 @@ def fused_mttkrp_nmode_gather_stream(
         [
             pl.BlockSpec((blk,), lambda s, b, tiles, *scheds: (b,)),
             pl.BlockSpec((blk,), lambda s, b, tiles, *scheds: (b,)),
-            pl.BlockSpec((blk, n_in),
-                         lambda s, b, tiles, *scheds: (b, 0)),
+            pl.BlockSpec((n_in, blk),
+                         lambda s, b, tiles, *scheds: (0, b)),
         ]
         + [
             # Window slot j of mode w: one frow_tile-row, rank_slab-wide
@@ -978,6 +1103,7 @@ def fused_mttkrp_nmode_gather_stream(
         # straight from the SMEM prefetch refs — no VMEM copy).
         input_output_aliases={4 + n_in + sum(window_tiles): 0},
         interpret=interpret,
+        compiler_params=_compiler_params(),
     )(tile_of_block, *tile_schedules, local_row_in_tile, vals, idx_stream,
       *window_operands, out_init)
 

@@ -22,9 +22,13 @@ must contain a ``tpu_custom_call`` (the serialized Mosaic module), for
 ``ref`` it must simply lower (plain XLA).
 
 Compiled-geometry constraint (Mosaic, not this harness): the kernels'
-rank-1 ``(blk,)`` scalar-stream blocks require ``blk % 128 == 0``
+rank-1 ``(blk,)`` scalar-stream blocks require ``blk % 1024 == 0``
 (:data:`MOSAIC_BLK_MULTIPLE`); the interpreter accepts any ``blk``.
 Geometries here respect it — see :func:`compiled_geometry_ok`.
+
+Lowering is not compiling: a kernel that lowers may still be refused by
+the TPU compiler (tiling, VMEM, SMEM). ``tests/test_tpu_compile.py``
+compiles the main path at real widths for a described v5e.
 
 Entry points: :func:`lower_backend` (one check), :func:`run` (a grid →
 ``LoweringResult`` rows, the payload of ``BENCH_lowering.json``), and
@@ -56,10 +60,11 @@ __all__ = [
 ]
 
 # Mosaic requires rank-1 block shapes — the kernels' (blk,) value/row
-# streams — to be a multiple of the 128-lane tiling (or the whole array
-# dimension, which the blocked layout never is). Execution-mode
-# geometry constraint only: the interpreter accepts any blk.
-MOSAIC_BLK_MULTIPLE = 128
+# streams — to match XLA's 1024-element tiling of rank-1 arrays (or be
+# the whole array dimension, which the blocked layout never is).
+# Execution-mode geometry constraint only: the interpreter accepts any
+# blk. kernel.py owns the rule and raises on a compiled call that breaks it.
+MOSAIC_BLK_MULTIPLE = _kernel.COMPILED_BLK_MULTIPLE
 
 
 @dataclasses.dataclass(frozen=True)
@@ -105,18 +110,18 @@ class Geometry:
 # backend, each exercising a distinct BlockSpec regime (whole-rank vs
 # rank-slab factors, window width 1 vs >1), all < a second to lower.
 SMOKE_GEOMETRIES = (
-    Geometry(nmodes=3, rank=128, blk=128, tile_rows=8, factor_rows=64),
-    Geometry(nmodes=4, rank=128, blk=128, tile_rows=128, factor_rows=96),
-    Geometry(nmodes=3, rank=256, blk=256, tile_rows=8, factor_rows=300),
+    Geometry(nmodes=3, rank=128, blk=1024, tile_rows=8, factor_rows=64),
+    Geometry(nmodes=4, rank=128, blk=1024, tile_rows=128, factor_rows=96),
+    Geometry(nmodes=3, rank=256, blk=1024, tile_rows=8, factor_rows=300),
 )
 
 # The slow full grid: adds a 5-mode point, a non-128-multiple rank (the
 # pad_rank path), wide blocks, and a many-tile stream window.
 FULL_GEOMETRIES = SMOKE_GEOMETRIES + (
-    Geometry(nmodes=5, rank=128, blk=128, tile_rows=16, factor_rows=64),
-    Geometry(nmodes=3, rank=200, blk=128, tile_rows=8, factor_rows=64),
-    Geometry(nmodes=3, rank=512, blk=384, tile_rows=128, factor_rows=700),
-    Geometry(nmodes=4, rank=256, blk=256, tile_rows=32, factor_rows=1000,
+    Geometry(nmodes=5, rank=128, blk=1024, tile_rows=16, factor_rows=64),
+    Geometry(nmodes=3, rank=200, blk=1024, tile_rows=8, factor_rows=64),
+    Geometry(nmodes=3, rank=512, blk=2048, tile_rows=128, factor_rows=700),
+    Geometry(nmodes=4, rank=256, blk=1024, tile_rows=32, factor_rows=1000,
              num_tiles=8, nnz_cap=1024),
 )
 
@@ -149,13 +154,17 @@ def compiled_geometry_ok(geom: Geometry) -> tuple[bool, str]:
 
     Returns ``(ok, reason)``. The only compiled-vs-interpret geometry
     restriction the kernels carry is the rank-1 block-shape rule on
-    ``blk``; everything else (rank, tile_rows, windows) is already
-    padded/tiled into Mosaic-legal shapes by construction.
+    ``blk``: XLA tiles a rank-1 int32/fp32 array in 1024 elements and
+    Mosaic refuses a ``(blk,)`` block that does not match. Rank,
+    tile_rows and windows are padded/tiled into Mosaic-legal shapes by
+    construction; what fits VMEM and SMEM is the residency planner's
+    question, not a geometry rule.
     """
     if geom.blk % MOSAIC_BLK_MULTIPLE != 0:
         return False, (f"blk={geom.blk} is not a multiple of "
-                       f"{MOSAIC_BLK_MULTIPLE}: Mosaic rejects the rank-1 "
-                       "(blk,) scalar-stream blocks")
+                       f"{MOSAIC_BLK_MULTIPLE}: Mosaic rejects rank-1 "
+                       "(blk,) scalar-stream blocks that do not match "
+                       "XLA's 1024-element tiling")
     return True, ""
 
 

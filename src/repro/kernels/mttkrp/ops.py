@@ -64,6 +64,7 @@ __all__ = [
     "mttkrp_blocked",
     "mttkrp_device_step",
     "pad_rank",
+    "runtime_blk",
     "select_backend",
     "step_traffic_bytes",
     "tile_schedule",
@@ -217,6 +218,7 @@ def select_backend(
     vmem_budget: int = VMEM_BUDGET_BYTES,
     table=None,
     factor_rows=None,
+    num_blocks: int | None = None,
 ) -> str:
     """Resolve ``auto`` to a concrete backend; pass others through.
 
@@ -229,7 +231,9 @@ def select_backend(
     streamed member included — is then never chosen: its feasibility
     cannot be certified. ``mttkrp_device_step`` always passes the
     per-mode sequence, so end-to-end ``auto`` prefers the gather family
-    whenever it fits.
+    whenever it fits. ``num_blocks`` (the aligned stream's block count,
+    also passed by ``mttkrp_device_step``) adds the streaming rung's SMEM
+    check (:func:`repro.oocore.planner.backend_fits`).
 
     When a calibration ``table`` (a ``repro.tune`` ``CalibrationTable``
     or ``CostModel`` — anything with a ``best_backend`` method) is
@@ -292,16 +296,29 @@ def select_backend(
         if choice is not None and not _planner.backend_fits(
                 choice, nmodes=nmodes, rank=rank, blk=blk,
                 tile_rows=tile_rows, factor_rows=factor_rows,
-                vmem_budget=vmem_budget):
+                vmem_budget=vmem_budget, num_blocks=num_blocks):
             choice = None               # infeasible extrapolation
         if choice is not None:
             _obs.add("dispatch.backend", backend=choice, source="table")
             return choice
     chosen = _planner.plan_residency(
         nmodes=nmodes, rank=rank, blk=blk, tile_rows=tile_rows,
-        factor_rows=factor_rows, vmem_budget=vmem_budget).backend
+        factor_rows=factor_rows, vmem_budget=vmem_budget,
+        num_blocks=num_blocks).backend
     _obs.add("dispatch.backend", backend=chosen, source="static")
     return chosen
+
+
+def runtime_blk(g: int, *, compiled: bool) -> int:
+    """Nonzero block a runtime uses for FLYCOO shard size ``g``.
+
+    Compiled kernels need ``blk % COMPILED_BLK_MULTIPLE == 0`` (see
+    ``kernel.py``), so they get exactly that; the interpreter keeps the
+    shard-sized block, capped at 512.
+    """
+    if compiled:
+        return _kernel.COMPILED_BLK_MULTIPLE
+    return min(g, 512)
 
 
 def n_pad_for(cap: int, rows_cap: int, blk: int, tile_rows: int) -> int:
@@ -526,6 +543,7 @@ def mttkrp_device_step(idx, val, valid, factors, *, mode: int, rows_cap: int,
     backend = select_backend(
         backend, nmodes=nmodes, rank=rank, blk=blk, tile_rows=tile_rows,
         factor_rows=tuple(factors[w].shape[0] for w in in_modes),
+        num_blocks=n_pad_for(idx.shape[0], rows_cap, blk, tile_rows) // blk,
     )
 
     def _dispatch(backend: str, interpret, gather_dtype=gather_dtype):
@@ -541,8 +559,9 @@ def mttkrp_device_step(idx, val, valid, factors, *, mode: int, rows_cap: int,
             gdt = jnp.bfloat16 if gather_dtype == "bfloat16" else jnp.float32
             vals = jnp.where(valid, val, 0.0)
             n_pad = n_pad_for(local_row.shape[0], rows_cap, blk, tile_rows)
-            idx_in = jnp.stack([idx[:, w] for w in in_modes], axis=1)
-            idx_in = jnp.where(valid[:, None], idx_in, 0).astype(jnp.int32)
+            idx_cols = [jnp.where(valid, idx[:, w], 0).astype(jnp.int32)
+                        for w in in_modes]
+            idx_in = jnp.stack(idx_cols, axis=1)
             # max_rows is static (factor shapes), so host-side sorts
             # derive the identical Morton bit budget — and huge modes
             # widen the key words instead of clamping tile ids.
@@ -567,7 +586,9 @@ def mttkrp_device_step(idx, val, valid, factors, *, mode: int, rows_cap: int,
                 # the gather dtype is what halves both the VMEM residency
                 # and the factor-load traffic for bf16 (same values as the
                 # materialized path's cast-then-take).
-                idx_al = _align_to_blocks(idx_in, slot, n_pad)
+                # Mode-major (K, n_pad): each mode's stream lane-dense.
+                idx_al = jnp.stack([_align_to_blocks(c, slot, n_pad)
+                                    for c in idx_cols])
                 fmats = tuple(pad_rank(factors[w].astype(gdt))
                               for w in in_modes)
                 if backend == STREAM_BACKEND:
@@ -580,7 +601,7 @@ def mttkrp_device_step(idx, val, valid, factors, *, mode: int, rows_cap: int,
                     fmats = tuple(_pad_factor_rows(f, frow) for f in fmats)
                     scheds = tuple(
                         tile_schedule(
-                            idx_al[:, i], blk,
+                            idx_al[i], blk,
                             _planner.stream_window_tiles(blk, f.shape[0]))
                         for i, f in enumerate(fmats))
                     out = _kernel.fused_mttkrp_nmode_gather_stream(

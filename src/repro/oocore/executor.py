@@ -334,7 +334,7 @@ def mttkrp_out_of_core(
                     # the unit the retry policy replays.
                     _faults.fault_site("oocore.chunk")
                     return _kernel.fused_mttkrp_nmode_gather_stream(
-                        v_al[sl], idx_al[sl], fmats, r_al[sl],
+                        v_al[sl], idx_al[sl].T, fmats, r_al[sl],
                         tile_of_block[start:stop],
                         tuple(s[start:stop, :cw[i]]
                               for i, s in enumerate(scheds)),

@@ -53,6 +53,7 @@ __all__ = [
     "MIN_MXU_RANK",
     "VMEM_BUDGET_BYTES",
     "STREAM_BACKEND",
+    "SMEM_BUDGET_BYTES",
     "FactorResidency",
     "ResidencyPlan",
     "StreamTraffic",
@@ -64,6 +65,7 @@ __all__ = [
     "plan_residency",
     "predict_stream_traffic",
     "stream_chunk_bytes",
+    "stream_smem_bytes",
     "stream_window_tiles",
 ]
 
@@ -82,6 +84,8 @@ VMEM_BUDGET_BYTES = _kernel.VMEM_BUDGET_BYTES
 
 # The out-of-core backend this package adds to ops.BACKENDS.
 STREAM_BACKEND = _kernel.STREAM_BACKEND_NAME
+
+SMEM_BUDGET_BYTES = _kernel.SMEM_BUDGET_BYTES
 
 
 # R rounded up to the MXU lane multiple — aliased from kernel.py, the
@@ -106,6 +110,20 @@ def stream_window_tiles(blk: int, rows: int,
     dispatch (which cannot look at data) plans with the bound.
     """
     return min(blk, factor_row_tiles(rows, frow_tile))
+
+
+def stream_smem_bytes(num_blocks: int, window_tiles: Sequence[int]) -> int:
+    """SMEM the stream kernel's scalar-prefetched operands occupy.
+
+    ``tile_of_block`` (``num_blocks`` int32, 1024-word tiles) plus one
+    ``(num_blocks, W_w)`` int32 schedule per input mode, which SMEM pads
+    to (8, 128)-word tiles. All of it is prefetched whole, so it grows
+    with the nonzero count — unlike the VMEM window.
+    """
+    def up(x, m):
+        return -(-x // m) * m
+    scheds = sum(up(num_blocks, 8) * up(int(w), 128) for w in window_tiles)
+    return 4 * (up(num_blocks, 1024) + scheds)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -191,7 +209,8 @@ def backend_fits(backend: str, *, nmodes: int, rank: int, blk: int,
                  tile_rows: int, factor_rows=None,
                  vmem_budget: int = VMEM_BUDGET_BYTES,
                  gather_itemsize: int = 4,
-                 window_tiles: Sequence[int] | None = None) -> bool:
+                 window_tiles: Sequence[int] | None = None,
+                 num_blocks: int | None = None) -> bool:
     """Hard VMEM feasibility of one backend — the single predicate.
 
     This is what bounds a calibration table's preference in
@@ -207,6 +226,11 @@ def backend_fits(backend: str, *, nmodes: int, rank: int, blk: int,
     ones — :func:`predict_stream_traffic` on a locality-reordered
     stream (``repro.reorder``) typically certifies the rung at budgets
     the data-blind bound cannot.
+
+    ``num_blocks`` (the aligned stream's block count) lets the streaming
+    rung also be checked against :data:`SMEM_BUDGET_BYTES`: its tile
+    schedules are scalar-prefetched whole (:func:`stream_smem_bytes`).
+    ``None`` (a shape-only query) leaves SMEM unchecked.
     """
     if backend.endswith("_bf16"):
         backend = backend[:-len("_bf16")]
@@ -239,6 +263,9 @@ def backend_fits(backend: str, *, nmodes: int, rank: int, blk: int,
             windows = tuple(stream_window_tiles(blk, r) for r in per_mode)
         else:
             windows = (stream_window_tiles(blk, total),) * k
+        if num_blocks is not None and stream_smem_bytes(
+                num_blocks, windows) > SMEM_BUDGET_BYTES:
+            return False
         return _kernel.gather_stream_vmem_bytes(
             k, rpad, blk, tile_rows, windows,
             gather_itemsize=gather_itemsize) <= vmem_budget
@@ -273,7 +300,8 @@ def plan_residency(*, nmodes: int, rank: int, blk: int = 512,
                    vmem_budget: int = VMEM_BUDGET_BYTES,
                    gather_itemsize: int = 4,
                    allow_stream: bool = True,
-                   window_tiles: Sequence[int] | None = None
+                   window_tiles: Sequence[int] | None = None,
+                   num_blocks: int | None = None
                    ) -> ResidencyPlan:
     """The full static residency ladder for one mode step.
 
@@ -303,7 +331,8 @@ def plan_residency(*, nmodes: int, rank: int, blk: int = 512,
     stream rung gets certified — and picked — at budgets where the
     static bound overflows. The ladder stays monotone in the budget:
     the override only changes rung 4's (fixed) byte cost, never the
-    predicate shape.
+    predicate shape. ``num_blocks`` adds rung 4's SMEM check (see
+    :func:`backend_fits`).
     """
     k, rpad = nmodes - 1, padded_rank(rank)
     gi = gather_itemsize
@@ -345,7 +374,8 @@ def plan_residency(*, nmodes: int, rank: int, blk: int = 512,
                                        min(rpad, _kernel.RANK_SLAB), gi))
         if allow_stream and backend_fits(STREAM_BACKEND,
                                          factor_rows=factor_rows,
-                                         window_tiles=window_tiles, **kw):
+                                         window_tiles=window_tiles,
+                                         num_blocks=num_blocks, **kw):
             if window_tiles is not None:
                 windows = tuple(int(w) for w in window_tiles)
             elif per_mode is not None:

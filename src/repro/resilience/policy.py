@@ -9,7 +9,9 @@ injected :class:`~repro.resilience.faults.ResourceFault`), the dispatch
 steps one rung **down** — every lower rung computes the same MTTKRP with
 a strictly smaller working set — and when the compiled path itself is
 the problem it flips compiled → interpret (an explicit override through
-``runtime.execution.resolve_interpret``). Transient faults get bounded
+``runtime.execution.resolve_interpret``) — except under
+``execution_mode="compiled"``, where the fault propagates. Transient
+faults get bounded
 retry with exponential backoff. Corruption faults are never retried and
 never degraded through — a wrong answer must not be computable from bad
 bytes, so they propagate.
@@ -149,11 +151,16 @@ class RetryPolicy:
           same rung (counted ``resilience.interpret_fallbacks``);
         * :class:`ResourceFault` under interpret — step one rung down
           the ladder (counted ``resilience.degradations{from,to}``);
+        * under ``execution_mode="compiled"`` the flip is never taken:
+          the fault propagates, since that mode promises compiled
+          kernels or an error;
         * :class:`CorruptionFault` — propagate immediately;
         * ladder/retries exhausted — :class:`ResilienceExhausted`
           chained to the last fault. Never a silent wrong answer.
         """
-        from ..runtime.execution import ExecutionModeError, resolve_interpret
+        from ..runtime.execution import (ExecutionModeError,
+                                         get_execution_mode,
+                                         resolve_interpret)
 
         current = backend
         cur_interpret = interpret
@@ -190,6 +197,8 @@ class RetryPolicy:
                         # same answer as ExecutionModeError.
                         was_interpret = False
                 if not was_interpret:
+                    if get_execution_mode() == "compiled":
+                        raise
                     cur_interpret = True
                     _obs.add("resilience.interpret_fallbacks",
                              backend=current)

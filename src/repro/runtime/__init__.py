@@ -1,10 +1,9 @@
-from .execution import (CAPABILITY, Capability, ExecutionModeError,
+from .execution import (Capability, ExecutionModeError,
                         EXECUTION_MODES, execution_mode, get_execution_mode,
                         resolve_interpret, set_execution_mode)
 from .fault_tolerance import TrainLoopRunner, StragglerMonitor
 
 __all__ = [
-    "CAPABILITY",
     "Capability",
     "ExecutionModeError",
     "EXECUTION_MODES",

@@ -19,9 +19,10 @@ Three modes (:data:`EXECUTION_MODES`):
     attached TPU, otherwise interpret (the fallback reason is logged
     once and recorded in :func:`describe_meta`).
 
-The capability probe runs once at import of this module (the dispatch
-layer's import), answering "can a ``pallas_call(interpret=False)``
-*execute* here?". Note the distinction from *lowering*: StableHLO +
+The capability probe runs once, lazily, the first time a mode is
+resolved (:func:`capability`) — importing the package touches no JAX
+backend — answering "can a ``pallas_call(interpret=False)`` *execute*
+here?". Note the distinction from *lowering*: StableHLO +
 Mosaic lowering works on any host via the AOT path
 (``jax.jit(f).trace(...).lower(lowering_platforms=("tpu",))``) — that is
 what ``repro.kernels.mttkrp.lowering`` validates on CPU-only CI.
@@ -34,6 +35,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import logging
 
 from ..obs import counters as _obs
@@ -43,7 +45,8 @@ __all__ = [
     "EXECUTION_MODES",
     "Capability",
     "ExecutionModeError",
-    "CAPABILITY",
+    "capability",
+    "compiles",
     "probe_capability",
     "get_execution_mode",
     "set_execution_mode",
@@ -99,8 +102,11 @@ def probe_capability() -> Capability:
                 "(repro.kernels.mttkrp.lowering)"))
 
 
-# Probed at import of the dispatch module, per the policy contract above.
-CAPABILITY = probe_capability()
+@functools.cache
+def capability() -> Capability:
+    """The probe's answer for this process, probed on first use."""
+    return probe_capability()
+
 
 _mode: str = "auto"
 _fallback_logged: bool = False
@@ -139,7 +145,7 @@ def execution_mode(mode: str):
     """Context manager: run a block under ``mode``, then restore."""
     previous = set_execution_mode(mode)
     try:
-        yield CAPABILITY
+        yield capability()
     finally:
         set_execution_mode(previous)
 
@@ -171,28 +177,41 @@ def resolve_interpret(override: bool | None = None,
         _obs.add("execution.resolve", mode=mode, interpret=True)
         return True
     if mode == "compiled":
-        if not CAPABILITY.can_compile:
+        if not capability().can_compile:
             raise ExecutionModeError(
                 "execution_mode='compiled' but compiled Pallas execution "
-                f"is unavailable: {CAPABILITY.reason}. Use "
+                f"is unavailable: {capability().reason}. Use "
                 "execution_mode='interpret' (or 'auto', which falls back "
                 "with this reason) on this host.")
         _obs.add("execution.resolve", mode=mode, interpret=False)
         return False
     # auto
-    if CAPABILITY.can_compile:
+    if capability().can_compile:
         _obs.add("execution.resolve", mode=mode, interpret=False)
         return False
     if not _fallback_logged:
         # Strictly once per process, and as an obs event first: the
         # counted `execution.fallback` record survives into traces and
         # reports even when nobody configured logging.
-        _obs.add("execution.fallback", platform=CAPABILITY.platform)
+        _obs.add("execution.fallback", platform=capability().platform)
         _LOG.info("execution_mode='auto' resolves to interpret: %s",
-                  CAPABILITY.reason)
+                  capability().reason)
         _fallback_logged = True
     _obs.add("execution.resolve", mode=mode, interpret=True)
     return True
+
+
+def compiles() -> bool:
+    """Will kernels compile (not interpret) under the session mode?
+
+    For static choices made before any kernel runs (the runtime's block
+    size). Unlike :func:`resolve_interpret` it counts nothing, passes no
+    fault site and never raises: ``"compiled"`` answers True even where
+    the first kernel call will then refuse to run.
+    """
+    if _mode == "auto":
+        return capability().can_compile
+    return _mode == "compiled"
 
 
 def default_interpret() -> bool:
@@ -216,5 +235,5 @@ def describe_meta() -> dict:
     return dict(
         execution_mode=_mode,
         interpret=interpret,
-        execution_probe=CAPABILITY.reason or "tpu",
+        execution_probe=capability().reason or "tpu",
     )

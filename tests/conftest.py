@@ -1,76 +1,10 @@
-"""Shared fixtures + optional-dependency shims.
+"""Shared fixtures.
 
 NOTE: no XLA_FLAGS here — smoke tests must see the real single CPU device;
 multi-device tests spawn subprocesses (test_distributed).
-
-``hypothesis`` is an *optional* dependency: when absent, a stub module is
-installed before test collection so the five property-test files still
-import cleanly, with every ``@given`` test skipped with a clear reason
-instead of erroring the whole collection.
 """
-import sys
-import types
-
 import numpy as np
 import pytest
-
-_HYPOTHESIS_SKIP_REASON = (
-    "hypothesis not installed (optional dependency) — property-based sweep "
-    "skipped; example-based tests cover the same kernels"
-)
-
-
-def _install_hypothesis_stub() -> None:
-    try:
-        import hypothesis  # noqa: F401
-        return
-    except ImportError:
-        pass
-
-    mod = types.ModuleType("hypothesis")
-    mod.__repro_stub__ = True
-
-    def given(*_args, **_kwargs):
-        def deco(fn):
-            return pytest.mark.skip(reason=_HYPOTHESIS_SKIP_REASON)(fn)
-        return deco
-
-    class _Settings:
-        """Accepts any decorator/profile usage and is a no-op."""
-
-        def __init__(self, *args, **kwargs):
-            pass
-
-        def __call__(self, fn):
-            return fn
-
-        @staticmethod
-        def register_profile(*args, **kwargs):
-            pass
-
-        @staticmethod
-        def load_profile(*args, **kwargs):
-            pass
-
-    def assume(condition):
-        return bool(condition)
-
-    strategies = types.ModuleType("hypothesis.strategies")
-    # Any strategy constructor (integers, floats, sampled_from, ...) returns
-    # an inert placeholder — @given skips the test before strategies matter.
-    strategies.__getattr__ = lambda name: (lambda *a, **k: None)
-
-    mod.given = given
-    mod.settings = _Settings
-    mod.assume = assume
-    mod.strategies = strategies
-    mod.HealthCheck = types.SimpleNamespace(
-        too_slow=None, data_too_large=None, filter_too_much=None)
-    sys.modules["hypothesis"] = mod
-    sys.modules["hypothesis.strategies"] = strategies
-
-
-_install_hypothesis_stub()
 
 
 @pytest.fixture(scope="session")

@@ -50,3 +50,20 @@ def test_four_mode_tensor():
     res = cp_als(t, rank=4, iters=5, seed=7)
     assert np.isfinite(res.fit)
     assert len(res.factors) == 4
+
+
+def test_cp_als_distributed_refuses_worker_mesh_mismatch():
+    # An 8-worker FLYCOO tensor on a 1-device mesh would run with the
+    # wrong owner layout; the driver must refuse it up front.
+    import jax
+    from jax.sharding import Mesh
+
+    from repro.core import distributed as dist
+    from repro.core.cpals import cp_als_distributed
+    from repro.core.flycoo import build_flycoo
+
+    t = random_sparse_tensor((20, 16, 12), 200, seed=0)
+    ft = build_flycoo(t, 8, m_bounds=(2, 8), g_bounds=(8, 64))
+    mesh = Mesh(np.array(jax.devices()[:1]), (dist.AXIS,))
+    with pytest.raises(ValueError, match="8 workers"):
+        cp_als_distributed(ft, 4, mesh, iters=1)

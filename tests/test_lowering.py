@@ -15,10 +15,15 @@ Coverage per the issue checklist:
     ``src/`` or ``benchmarks/`` outside the policy module — every call
     site defers to ``repro.runtime.execution``;
   * the one-hot MXU gather (the compiled path's ``jnp.take``
-    replacement) is bitwise the take-based gather, fp32 and bf16.
+    replacement) is bitwise the take-based gather, fp32 and bf16, whole
+    and walked in row chunks;
+  * importing the package initialises no JAX backend, and the benchmark
+    driver exits nonzero when a suite errors.
 """
 import os
 import re
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -77,13 +82,15 @@ def test_smoke_grid_meets_issue_floor():
 
 
 def test_non_mosaic_blk_is_reported_not_raised():
-    # blk=32 violates the rank-1 block rule: the harness must return a
-    # failing result (with the Mosaic message), never raise.
-    geom = klow.Geometry(nmodes=3, rank=128, blk=32, tile_rows=8)
+    # blk=512 violates the rank-1 block rule (XLA tiles rank-1 arrays in
+    # 1024 elements): the harness must return a failing result with the
+    # rule, never raise.
+    geom = klow.Geometry(nmodes=3, rank=128, blk=512, tile_rows=8)
     ok, reason = klow.compiled_geometry_ok(geom)
-    assert not ok and "128" in reason
+    assert not ok and "1024" in reason
+    assert klow.MOSAIC_BLK_MULTIPLE == 1024
     r = klow.lower_backend("pallas_fused", geom)
-    assert not r.ok and r.error
+    assert not r.ok and "1024" in r.error
 
 
 # ---------------------------------------------------------------------------
@@ -91,10 +98,32 @@ def test_non_mosaic_blk_is_reported_not_raised():
 # ---------------------------------------------------------------------------
 
 def test_probe_on_cpu_host():
-    cap = execution.CAPABILITY
+    cap = execution.capability()
     assert not cap.can_compile
     assert cap.platform == jax.default_backend()
     assert "tpu" in cap.reason.lower() or "mosaic" in cap.reason.lower()
+
+
+def test_import_touches_no_backend():
+    # The capability probe is lazy: importing the package must not
+    # initialise a JAX backend (on a TPU host that would take the chip).
+    code = ("import jax._src.xla_bridge as xb\n"
+            "import repro.runtime, repro.kernels.mttkrp.ops, repro.core.cpals\n"
+            "assert not xb._backends, sorted(xb._backends)\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(REPO_ROOT, "src"))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_runtime_blk_follows_execution_mode():
+    assert kops.runtime_blk(64, compiled=False) == 64
+    assert kops.runtime_blk(4096, compiled=False) == 512
+    assert kops.runtime_blk(64, compiled=True) == klow.MOSAIC_BLK_MULTIPLE
+    with execution.execution_mode("compiled"):
+        assert execution.compiles()
+    with execution.execution_mode("interpret"):
+        assert not execution.compiles()
 
 
 def test_auto_resolves_to_interpret_with_reason_surfaced():
@@ -113,7 +142,7 @@ def test_compiled_mode_raises_clear_error():
         execution.resolve_interpret(mode="compiled")
     msg = str(exc.value)
     assert "compiled" in msg
-    assert execution.CAPABILITY.reason in msg     # probe reason surfaced
+    assert execution.capability().reason in msg     # probe reason surfaced
     assert "interpret" in msg                     # and a way out
 
 
@@ -196,7 +225,7 @@ def test_selection_and_residency_invariant_under_mode(case):
     backend=st.sampled_from(kops.BACKENDS),
     nmodes=st.integers(3, 5),
     rank=st.sampled_from([8, 100, 128, 256]),
-    blk=st.sampled_from([128, 256]),
+    blk=st.sampled_from([1024, 2048]),
     tile_rows=st.sampled_from([8, 16, 128]),
     factor_rows=st.sampled_from([64, 130, 300]),
 )
@@ -267,3 +296,47 @@ def test_onehot_gather_bitwise_equals_take(dtype):
     assert np.array_equal(np.asarray(take.astype(jnp.float32)),
                           np.asarray(onehot))
     assert onehot.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("rows", [96, 100], ids=["whole-chunks", "ragged"])
+def test_chunked_onehot_gather_equals_take(rows):
+    # The compiled gather walks the factor ref in chunk_rows slices, the
+    # last one ragged when the factor is not a multiple of it.
+    from jax.experimental import pallas as pl
+    rng = np.random.default_rng(7)
+    matrix = jnp.asarray(rng.standard_normal((rows, 128)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, rows, size=64).astype(np.int32))
+
+    def body(m_ref, i_ref, o_ref):
+        o_ref[...] = kkernel._gather_rows(m_ref, i_ref[...], onehot=True,
+                                          chunk_rows=32)
+
+    chunked = pl.pallas_call(
+        body, out_shape=jax.ShapeDtypeStruct((64, 128), jnp.float32),
+        interpret=True)(matrix, idx)
+    take = kkernel._gather_rows(matrix, idx, onehot=False)
+    assert np.array_equal(np.asarray(take), np.asarray(chunked))
+
+
+# ---------------------------------------------------------------------------
+# Entry points: a failed suite is not swallowed
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("fails", [False, True], ids=["ok", "errored"])
+def test_bench_driver_exit_code(fails, monkeypatch, tmp_path):
+    monkeypatch.syspath_prepend(REPO_ROOT)
+    from benchmarks import run as bench_run
+
+    def suite(quick=True):
+        if fails:
+            raise RuntimeError("suite failed")
+        return [dict(bench="probe", status="ok")]
+
+    monkeypatch.setattr(bench_run, "SUITES", {"probe": suite})
+    # main() points BENCH_*.json at --out; restore it afterwards.
+    monkeypatch.setattr(bench_run.common, "BENCH_OUT_DIR",
+                        bench_run.common.BENCH_OUT_DIR)
+    monkeypatch.setattr(bench_run, "enable_compile_cache", lambda: None)
+    monkeypatch.setattr(sys, "argv", ["run", "--only", "probe",
+                                      "--out", str(tmp_path)])
+    assert bench_run.main() == int(fails)

@@ -257,6 +257,18 @@ def test_dispatch_resource_flips_compiled_to_interpret_first():
     assert reg.total("resilience.degradations") == 0
 
 
+def test_dispatch_resource_under_compiled_mode_raises():
+    # execution_mode="compiled" promises compiled kernels or an error:
+    # the policy must not flip to the interpreter behind it.
+    from repro.runtime import execution
+    call, log = _scripted_call([ResourceFault("ops.kernel", 0), "ok"])
+    with execution.execution_mode("compiled"), ocnt.use_registry() as reg:
+        with pytest.raises(ResourceFault):
+            RetryPolicy().dispatch(call, "pallas_fused", False)
+    assert log == [("pallas_fused", False)]
+    assert reg.total("resilience.interpret_fallbacks") == 0
+
+
 def test_dispatch_resource_under_interpret_steps_down():
     call, log = _scripted_call([ResourceFault("ops.kernel", 0), "ok"])
     with ocnt.use_registry() as reg:
