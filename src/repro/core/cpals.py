@@ -28,6 +28,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from ..obs import counters as _obs
+from ..obs import scopes as _scopes
 from ..obs import tracer as _tracer
 from ..resilience import checkpoint as _ckpt
 from ..resilience import numerics as _numerics
@@ -201,6 +202,10 @@ def make_als_sweep(rt: dist.DynasorRuntime, mesh: Mesh, *,
     The MTTKRP → solve → normalize → remap chain per mode follows Alg. 1/2;
     the solve happens on owned rows only (owner-computes extends to the
     least-squares update), then an all_gather re-replicates the factor.
+
+    Each phase runs under a ``jax.named_scope`` of :mod:`repro.obs.scopes`
+    (``als_mode{n}/mttkrp|solve|remap``, then ``fit``), so a device
+    trace of the sweep can be split by phase.
     """
 
     def inner(idx, val, mask, x_norm_sq, *factors_lam):
@@ -211,35 +216,45 @@ def make_als_sweep(rt: dist.DynasorRuntime, mesh: Mesh, *,
         grams = [f.T @ f for f in factors]   # padding rows are 0 → exact
         M_last_local = A_last_local = None
         for n in range(rt.nmodes):
-            local_M = dist.device_mttkrp(idx, val, mask, factors, n, rt,
-                                         backend)
-            A_local = _solve_v(grams, n, local_M)
-            # Column norms need the full matrix: psum of local sums.
-            sq = jax.lax.psum(jnp.sum(A_local ** 2, axis=0), dist.AXIS)
-            mx = jax.lax.pmax(jnp.max(jnp.abs(A_local), axis=0), dist.AXIS)
-            norms = jnp.where(sweep0, jnp.sqrt(sq), jnp.maximum(mx, 1.0))
-            norms = jnp.where(norms == 0, 1.0, norms)
-            A_local = A_local / norms
-            lam = norms
-            full = jax.lax.all_gather(A_local, dist.AXIS, axis=0, tiled=True)
-            factors[n] = full
-            grams[n] = full.T @ full
-            if n == rt.nmodes - 1:
-                M_last_local, A_last_local = local_M, A_local
-            idx, val, mask, _ = dist.device_remap(
-                idx, val, mask, (n + 1) % rt.nmodes, rt)
-        # fit parts: <X, X̂> = Σ_r λ_r Σ_i M[i,r]·Â[i,r], owned rows psummed.
-        inner_term = jax.lax.psum(
-            jnp.einsum("ir,ir,r->", M_last_local, A_last_local, lam),
-            dist.AXIS)
-        R = lam.shape[0]
-        G = jnp.ones((R, R), jnp.float32)
-        for g in grams:
-            G = G * g
-        model_norm_sq = jnp.einsum("r,rs,s->", lam, G, lam)
-        resid_sq = jnp.maximum(x_norm_sq - 2.0 * inner_term + model_norm_sq,
-                               0.0)
-        fit = 1.0 - jnp.sqrt(resid_sq) / jnp.sqrt(x_norm_sq)
+            with jax.named_scope(_scopes.mode_scope(n)):
+                with jax.named_scope(_scopes.MTTKRP):
+                    local_M = dist.device_mttkrp(idx, val, mask, factors, n,
+                                                 rt, backend)
+                with jax.named_scope(_scopes.SOLVE):
+                    A_local = _solve_v(grams, n, local_M)
+                    # Column norms need the full matrix: psum of local sums.
+                    sq = jax.lax.psum(jnp.sum(A_local ** 2, axis=0),
+                                      dist.AXIS)
+                    mx = jax.lax.pmax(jnp.max(jnp.abs(A_local), axis=0),
+                                      dist.AXIS)
+                    norms = jnp.where(sweep0, jnp.sqrt(sq),
+                                      jnp.maximum(mx, 1.0))
+                    norms = jnp.where(norms == 0, 1.0, norms)
+                    A_local = A_local / norms
+                    lam = norms
+                    full = jax.lax.all_gather(A_local, dist.AXIS, axis=0,
+                                              tiled=True)
+                    factors[n] = full
+                    grams[n] = full.T @ full
+                if n == rt.nmodes - 1:
+                    M_last_local, A_last_local = local_M, A_local
+                with jax.named_scope(_scopes.REMAP):
+                    idx, val, mask, _ = dist.device_remap(
+                        idx, val, mask, (n + 1) % rt.nmodes, rt)
+        with jax.named_scope(_scopes.FIT):
+            # fit parts: <X, X̂> = Σ_r λ_r Σ_i M[i,r]·Â[i,r], owned rows
+            # psummed.
+            inner_term = jax.lax.psum(
+                jnp.einsum("ir,ir,r->", M_last_local, A_last_local, lam),
+                dist.AXIS)
+            R = lam.shape[0]
+            G = jnp.ones((R, R), jnp.float32)
+            for g in grams:
+                G = G * g
+            model_norm_sq = jnp.einsum("r,rs,s->", lam, G, lam)
+            resid_sq = jnp.maximum(
+                x_norm_sq - 2.0 * inner_term + model_norm_sq, 0.0)
+            fit = 1.0 - jnp.sqrt(resid_sq) / jnp.sqrt(x_norm_sq)
         return ((idx[None], val[None], mask[None]),
                 factors, lam, fit)
 

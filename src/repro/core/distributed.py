@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from typing import Callable, NamedTuple, Sequence
 
 import jax
@@ -45,6 +46,7 @@ from . import remap as remap_lib
 from .flycoo import FlycooTensor, pack_mode
 from ..kernels.mttkrp import ops as kops
 from ..obs import counters as _obs
+from ..obs import tracer as _tracer
 from ..resilience import faults as _faults
 from ..runtime import execution as _execution
 
@@ -194,41 +196,48 @@ def prepare_runtime(
         dynamic remapping between modes). ``None`` (default) inherits
         ``ft.ordering``, so a tensor built with
         ``build_flycoo(..., ordering=...)`` keeps its policy end to end.
+
+    Its wall time is counted once per call as ``runtime.prepare_s``, and
+    is a ``runtime.prepare`` span under an enabled tracer (:mod:`repro.obs`).
     """
-    ordering = ft.ordering if ordering is None else ordering
-    D = ft.params.num_workers
-    plans = None
-    if table is not None:
-        from ..tune.model import plan_modes  # deferred: tune imports core
-        plans = plan_modes(table, ft, rank, ordering=ordering)
-    tiles = (
-        tuple(p.tile_rows for p in plans) if plans is not None
-        else (tile_rows,) * ft.nmodes
-    )
-    rows_cap = tuple(
-        int(-(-mp.rows_cap // t) * t)                        # round to tile
-        for mp, t in zip(ft.modes, tiles)
-    )
-    i_pad = tuple(D * rc for rc in rows_cap)
-    if blk is None:
-        blk = kops.runtime_blk(ft.params.g, compiled=_execution.compiles())
-    caps = remap_lib.remap_capacities(ft)
-    # Count the per-transition all_to_all allocation here, once per
-    # runtime build — every driver (CP-ALS, benches, serving) that
-    # constructs a runtime gets its collective traffic into the obs
-    # registry without bench-side re-derivation.
-    _obs.record_remap_exchange(caps, D, ft.nmodes, uniform_cap=uniform_cap)
-    rt = DynasorRuntime(
-        num_workers=D, nmodes=ft.nmodes, rank=rank, rows_cap=rows_cap,
-        i_pad=i_pad, nnz_cap=ft.nnz_cap,
-        bucket_cap=max(caps), shape=ft.tensor.shape,
-        blk=blk, tile_rows=tile_rows,
-        bucket_caps=None if uniform_cap else tuple(caps),
-        mode_plans=plans, gather_dtype=gather_dtype, ordering=ordering,
-    )
-    # pack_mode used flycoo rows_cap; re-pad indices to tile-rounded layout.
-    idx, val, mask = pack_mode(ft, 0)
-    idx = _repad_indices(ft, idx, rows_cap)
+    t0 = time.perf_counter()
+    with _tracer.get_tracer().span("runtime.prepare"):
+        ordering = ft.ordering if ordering is None else ordering
+        D = ft.params.num_workers
+        plans = None
+        if table is not None:
+            from ..tune.model import plan_modes  # deferred: tune imports core
+            plans = plan_modes(table, ft, rank, ordering=ordering)
+        tiles = (
+            tuple(p.tile_rows for p in plans) if plans is not None
+            else (tile_rows,) * ft.nmodes
+        )
+        rows_cap = tuple(
+            int(-(-mp.rows_cap // t) * t)                    # round to tile
+            for mp, t in zip(ft.modes, tiles)
+        )
+        i_pad = tuple(D * rc for rc in rows_cap)
+        if blk is None:
+            blk = kops.runtime_blk(ft.params.g, compiled=_execution.compiles())
+        caps = remap_lib.remap_capacities(ft)
+        # Count the per-transition all_to_all allocation here, once per
+        # runtime build — every driver (CP-ALS, benches, serving) that
+        # constructs a runtime gets its collective traffic into the obs
+        # registry without bench-side re-derivation.
+        _obs.record_remap_exchange(caps, D, ft.nmodes, uniform_cap=uniform_cap)
+        rt = DynasorRuntime(
+            num_workers=D, nmodes=ft.nmodes, rank=rank, rows_cap=rows_cap,
+            i_pad=i_pad, nnz_cap=ft.nnz_cap,
+            bucket_cap=max(caps), shape=ft.tensor.shape,
+            blk=blk, tile_rows=tile_rows,
+            bucket_caps=None if uniform_cap else tuple(caps),
+            mode_plans=plans, gather_dtype=gather_dtype, ordering=ordering,
+        )
+        # pack_mode used flycoo rows_cap; re-pad indices to the tile-rounded
+        # layout.
+        idx, val, mask = pack_mode(ft, 0)
+        idx = _repad_indices(ft, idx, rows_cap)
+    _obs.add("runtime.prepare_s", time.perf_counter() - t0)
     return rt, (idx, val, mask)
 
 

@@ -24,10 +24,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import time
 from typing import Sequence
 
 import numpy as np
 
+from ..obs import counters as _obs
+from ..obs import tracer as _tracer
 from .schedule import block_cyclic_schedule, lpt_schedule
 from .tensors import SparseTensor
 
@@ -311,22 +314,29 @@ def build_flycoo(
     locality-aware nonzero ordering :func:`pack_mode` applies within
     each (owner, output row) group — paid once here at preprocessing
     time, amortized over every ALS sweep.
+
+    Its wall time is counted once per call as ``flycoo.build_s``, and is a
+    ``flycoo.build`` span under an enabled tracer (:mod:`repro.obs`).
     """
     from ..reorder import validate_ordering  # deferred: reorder imports kernels
-    validate_ordering(ordering)
-    _validate_tensor(t)
-    if params is None:
-        params = choose_partition_params(
-            t.shape, t.nnz, num_workers, rank=rank, cache_bytes=cache_bytes,
-            m_bounds=m_bounds, g_bounds=g_bounds, fused_gather=fused_gather,
-        )
-    modes = [
-        _build_mode(t, n, params.m[n], params.g, num_workers, schedule)
-        for n in range(t.nmodes)
-    ]
-    perm_indices = np.stack(
-        [modes[n].row_perm[t.indices[:, n]] for n in range(t.nmodes)], axis=1
-    ).astype(np.int64)
+    t0 = time.perf_counter()
+    with _tracer.get_tracer().span("flycoo.build"):
+        validate_ordering(ordering)
+        _validate_tensor(t)
+        if params is None:
+            params = choose_partition_params(
+                t.shape, t.nnz, num_workers, rank=rank,
+                cache_bytes=cache_bytes, m_bounds=m_bounds,
+                g_bounds=g_bounds, fused_gather=fused_gather,
+            )
+        modes = [
+            _build_mode(t, n, params.m[n], params.g, num_workers, schedule)
+            for n in range(t.nmodes)
+        ]
+        perm_indices = np.stack(
+            [modes[n].row_perm[t.indices[:, n]] for n in range(t.nmodes)],
+            axis=1).astype(np.int64)
+    _obs.add("flycoo.build_s", time.perf_counter() - t0)
     return FlycooTensor(tensor=t, params=params, modes=modes,
                         perm_indices=perm_indices, ordering=ordering)
 

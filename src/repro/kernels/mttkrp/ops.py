@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from . import kernel as _kernel
 from . import ref as _ref
 from ...obs import counters as _obs
+from ...obs import scopes as _scopes
 from ...obs import tracer as _tracer_mod
 from ...oocore import planner as _planner
 # Imported as the submodule path (not via the package __init__) so the
@@ -357,49 +358,54 @@ def build_block_layout(local_row, valid, *, rows_cap: int, blk: int,
       element in the aligned stream (``n_pad_for(...)`` = dump slot for
       invalid), ``tile_of_block[(n_pad//blk,)]`` non-decreasing output tile
       per block.
-    """
-    cap = local_row.shape[0]
-    num_tiles = rows_cap // tile_rows
-    n_pad = n_pad_for(cap, rows_cap, blk, tile_rows)
 
-    tile_of_elem = jnp.where(valid, local_row // tile_rows, num_tiles)
-    counts = jax.ops.segment_sum(
-        valid.astype(jnp.int32), tile_of_elem, num_segments=num_tiles + 1
-    )[:num_tiles]
-    padded = ((counts + blk - 1) // blk) * blk
-    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                               jnp.cumsum(padded).astype(jnp.int32)])
-    if order_keys:
-        # Rank within the tile run = position under the (tile, keys,
-        # position) lexsort — the jit twin of the host-side
-        # repro.reorder.locality_lexsort (same keys, same tiebreak, so
-        # the two produce bit-identical aligned streams).
-        pos = jnp.arange(cap, dtype=jnp.int32)
-        keys = tuple(jnp.asarray(kk).astype(jnp.int32) for kk in order_keys)
-        order = jnp.lexsort((pos,) + keys[::-1] + (tile_of_elem,))
-        inv = jnp.zeros(cap, jnp.int32).at[order].set(pos)
-        sorted_tiles = jnp.take(tile_of_elem, order)
-        first_of_tile = jnp.searchsorted(sorted_tiles, tile_of_elem,
-                                         side="left")
-        rank_in_tile = inv - first_of_tile.astype(jnp.int32)
-    else:
-        # Elements sorted by (valid desc, row asc) => per-tile runs
-        # contiguous; rank = distance from the run's first position.
-        first_of_tile = jnp.searchsorted(tile_of_elem, tile_of_elem,
-                                         side="left")
-        rank_in_tile = (jnp.arange(cap, dtype=jnp.int32)
-                        - first_of_tile.astype(jnp.int32))
-    slot = jnp.where(
-        valid,
-        jnp.take(offsets, tile_of_elem, fill_value=0) + rank_in_tile,
-        n_pad,
-    )
-    block_start = jnp.arange(n_pad // blk, dtype=jnp.int32) * blk
-    tile_of_block = jnp.clip(
-        jnp.searchsorted(offsets, block_start, side="right") - 1,
-        0, num_tiles - 1,
-    ).astype(jnp.int32)
-    return slot, tile_of_block
+    Runs under the ``layout`` named scope (:mod:`repro.obs.scopes`), so
+    its device time can be read from a trace on every rung that calls it.
+    """
+    with jax.named_scope(_scopes.LAYOUT):
+        cap = local_row.shape[0]
+        num_tiles = rows_cap // tile_rows
+        n_pad = n_pad_for(cap, rows_cap, blk, tile_rows)
+
+        tile_of_elem = jnp.where(valid, local_row // tile_rows, num_tiles)
+        counts = jax.ops.segment_sum(
+            valid.astype(jnp.int32), tile_of_elem,
+            num_segments=num_tiles + 1)[:num_tiles]
+        padded = ((counts + blk - 1) // blk) * blk
+        offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                   jnp.cumsum(padded).astype(jnp.int32)])
+        if order_keys:
+            # Rank within the tile run = position under the (tile, keys,
+            # position) lexsort — the jit twin of the host-side
+            # repro.reorder.locality_lexsort (same keys, same tiebreak, so
+            # the two produce bit-identical aligned streams).
+            pos = jnp.arange(cap, dtype=jnp.int32)
+            keys = tuple(jnp.asarray(kk).astype(jnp.int32)
+                         for kk in order_keys)
+            order = jnp.lexsort((pos,) + keys[::-1] + (tile_of_elem,))
+            inv = jnp.zeros(cap, jnp.int32).at[order].set(pos)
+            sorted_tiles = jnp.take(tile_of_elem, order)
+            first_of_tile = jnp.searchsorted(sorted_tiles, tile_of_elem,
+                                             side="left")
+            rank_in_tile = inv - first_of_tile.astype(jnp.int32)
+        else:
+            # Elements sorted by (valid desc, row asc) => per-tile runs
+            # contiguous; rank = distance from the run's first position.
+            first_of_tile = jnp.searchsorted(tile_of_elem, tile_of_elem,
+                                             side="left")
+            rank_in_tile = (jnp.arange(cap, dtype=jnp.int32)
+                            - first_of_tile.astype(jnp.int32))
+        slot = jnp.where(
+            valid,
+            jnp.take(offsets, tile_of_elem, fill_value=0) + rank_in_tile,
+            n_pad,
+        )
+        block_start = jnp.arange(n_pad // blk, dtype=jnp.int32) * blk
+        tile_of_block = jnp.clip(
+            jnp.searchsorted(offsets, block_start, side="right") - 1,
+            0, num_tiles - 1,
+        ).astype(jnp.int32)
+        return slot, tile_of_block
 
 
 def _align_to_blocks(x, slot, n_pad: int):

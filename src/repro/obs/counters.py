@@ -65,6 +65,7 @@ NAMESPACES = (
     "dryrun.lower_s",
     "execution.fallback",
     "execution.resolve",
+    "flycoo.build_s",
     "oocore.chunks",
     "oocore.dma.distinct_bytes",
     "oocore.dma.index_stream_bytes",
@@ -93,6 +94,7 @@ NAMESPACES = (
     "resilience.site_calls",
     "resilience.solve.guards",
     "resilience.table_fallbacks",
+    "runtime.prepare_s",
     "serve.decode_s",
     "serve.prefill_s",
     "serve.tokens",

@@ -17,6 +17,13 @@ or allocated per nonzero, and the process-default tracer is the
 :data:`NULL` no-op whose ``span`` returns a shared inert context
 manager, so uninstrumented runs pay only a function call.
 
+Where jax is already imported, each span of an enabled tracer is also a
+``jax.profiler.TraceAnnotation`` of its name: under an active
+``jax.profiler`` trace it lands on the ``/host:CPU`` plane, on the same
+clock as the device's ops, so a device idle gap can be put down to the
+span the host was in. This module still imports no jax itself, and the
+:data:`NULL` tracer never touches the profiler.
+
 Export targets the Chrome trace-event format (complete ``"X"`` events,
 microsecond ``ts``/``dur``), loadable in ``chrome://tracing`` and
 Perfetto; :func:`validate_chrome_trace` is the schema check CI's
@@ -32,6 +39,7 @@ import contextlib
 import dataclasses
 import json
 import os
+import sys
 import time
 
 from . import counters as _counters
@@ -114,12 +122,25 @@ class SpanRecord:
 
 class _Frame:
     __slots__ = ("sid", "parent", "depth", "name", "args", "t0", "snap",
-                 "child_delta")
+                 "child_delta", "annotation")
 
-    def __init__(self, sid, parent, depth, name, args, t0, snap):
+    def __init__(self, sid, parent, depth, name, args, t0, snap, annotation):
         self.sid, self.parent, self.depth = sid, parent, depth
         self.name, self.args, self.t0, self.snap = name, args, t0, snap
         self.child_delta: dict = {}
+        self.annotation = annotation
+
+
+def _profiler_annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``, or None
+    when jax has not been imported (this module never imports it)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    annotation = profiler.TraceAnnotation(name)
+    annotation.__enter__()
+    return annotation
 
 
 class _SpanCM:
@@ -162,17 +183,20 @@ class Tracer:
         sid, self._next_sid = self._next_sid, self._next_sid + 1
         parent = self._stack[-1].sid if self._stack else -1
         snap = _counters.get_registry().snapshot() if self._attach else None
-        # Clock AFTER the snapshot: registry-copy cost stays outside the
-        # measured interval.
+        annotation = _profiler_annotation(str(name))
+        # Clock AFTER the snapshot and the annotation: their cost stays
+        # outside the measured interval.
         self._stack.append(
             _Frame(sid, parent, len(self._stack), name, args,
-                   self._clock(), snap))
+                   self._clock(), snap, annotation))
 
     def _exit(self) -> None:
         if not self._stack:
             raise RuntimeError("span exit with no open span")
         t1 = self._clock()
         f = self._stack.pop()
+        if f.annotation is not None:
+            f.annotation.__exit__(None, None, None)
         delta: dict = {}
         self_delta: dict = {}
         if f.snap is not None:

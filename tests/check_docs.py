@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Docs consistency checks — run by the CI `docs` job and by pytest.
 
-Three checks, all stdlib-only (no jax import, so the CI job needs
+Five checks, all stdlib-only (no jax import, so the CI job needs
 nothing but a Python interpreter):
 
 1. **Intra-repo markdown links resolve.** Every relative
@@ -31,6 +31,11 @@ nothing but a Python interpreter):
    runtime; this closes the loop the other way — a namespace entry
    without a doc row fails CI.
 
+5. **docs/observability.md scope table ↔ obs SCOPES sync.** The rows
+   between the ``<!-- SCOPES:BEGIN/END -->`` markers must list exactly
+   the names of ``repro.obs.scopes.SCOPES`` (read with ``ast``): the
+   phase names the production sweep opens with ``jax.named_scope``.
+
 Exit status 0 iff all checks pass; failures are printed one per line.
 """
 from __future__ import annotations
@@ -51,6 +56,7 @@ LOWERING_COLUMN = "lowers (Mosaic)"
 COUNTERS_PATH = os.path.join(REPO_ROOT, "src", "repro", "obs",
                              "counters.py")
 OBS_DOC = os.path.join(REPO_ROOT, "docs", "observability.md")
+SCOPES_PATH = os.path.join(REPO_ROOT, "src", "repro", "obs", "scopes.py")
 
 # Names the matrix documents beyond ops.BACKENDS: the auto resolver and
 # the distributed layer's plain-XLA path.
@@ -202,37 +208,46 @@ def check_lowering_sync() -> list[str]:
     return errors
 
 
-def obs_namespaces() -> tuple[str, ...]:
-    """`NAMESPACES` from obs/counters.py via ast — no jax import."""
-    with open(COUNTERS_PATH, encoding="utf-8") as f:
-        tree = ast.parse(f.read(), filename=COUNTERS_PATH)
+def literal_tuple(path: str, name: str) -> tuple[str, ...]:
+    """A module-level pure-literal tuple ``name`` from ``path``, via ast."""
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read(), filename=path)
     for node in tree.body:
         if isinstance(node, ast.Assign) and any(
-                isinstance(t, ast.Name) and t.id == "NAMESPACES"
+                isinstance(t, ast.Name) and t.id == name
                 for t in node.targets):
             return tuple(ast.literal_eval(node.value))
-    raise AssertionError(f"no literal NAMESPACES assignment found in "
-                         f"{COUNTERS_PATH}")
+    raise AssertionError(f"no literal {name} assignment found in {path}")
 
 
-def documented_counters() -> set[str]:
-    """Counter names in observability.md's marked table rows."""
-    with open(OBS_DOC, encoding="utf-8") as f:
+def documented_rows(doc: str, marker: str) -> set[str]:
+    """First-column names of the table between ``<!-- marker:BEGIN/END
+    -->`` in ``doc``."""
+    with open(doc, encoding="utf-8") as f:
         text = f.read()
     try:
-        block = text.split("<!-- COUNTERS:BEGIN -->", 1)[1] \
-                    .split("<!-- COUNTERS:END -->", 1)[0]
+        block = text.split(f"<!-- {marker}:BEGIN -->", 1)[1] \
+                    .split(f"<!-- {marker}:END -->", 1)[0]
     except IndexError:
         raise AssertionError(
-            "docs/observability.md is missing the "
-            "<!-- COUNTERS:BEGIN/END --> markers around the counter "
-            "namespace table")
+            f"{os.path.relpath(doc, REPO_ROOT)} is missing the "
+            f"<!-- {marker}:BEGIN/END --> markers")
     names = set()
     for line in block.splitlines():
         m = _COUNTER_ROW_RE.match(line.strip())
         if m:
             names.add(m.group(1))
     return names
+
+
+def obs_namespaces() -> tuple[str, ...]:
+    """`NAMESPACES` from obs/counters.py via ast — no jax import."""
+    return literal_tuple(COUNTERS_PATH, "NAMESPACES")
+
+
+def documented_counters() -> set[str]:
+    """Counter names in observability.md's marked table rows."""
+    return documented_rows(OBS_DOC, "COUNTERS")
 
 
 def check_counter_sync() -> list[str]:
@@ -250,6 +265,15 @@ def check_counter_sync() -> list[str]:
             "but not in obs.counters.NAMESPACES — remove the row or "
             "register the name")
     return errors
+
+
+def check_scope_sync() -> list[str]:
+    code = set(literal_tuple(SCOPES_PATH, "SCOPES"))
+    docs = documented_rows(OBS_DOC, "SCOPES")
+    return ([f"docs/observability.md: scope `{s}` is in obs.scopes.SCOPES "
+             "but missing from the scope table" for s in sorted(code - docs)]
+            + [f"docs/observability.md: scope `{s}` is documented but not "
+               "in obs.scopes.SCOPES" for s in sorted(docs - code)])
 
 
 def check_backend_sync() -> list[str]:
@@ -274,17 +298,21 @@ def main() -> int:
     sync_errors = check_backend_sync()
     lowering_errors = check_lowering_sync()
     counter_errors = check_counter_sync()
-    for e in link_errors + sync_errors + lowering_errors + counter_errors:
+    scope_errors = check_scope_sync()
+    errors = (link_errors + sync_errors + lowering_errors + counter_errors
+              + scope_errors)
+    for e in errors:
         print(f"FAIL {e}")
-    if link_errors or sync_errors or lowering_errors or counter_errors:
+    if errors:
         return 1
     n_backends = len(ops_backends())
     n_lower = sum(lowering_status().values())
     print(f"docs checks passed: {checked} markdown links resolve, "
           f"{n_backends} backends in sync with docs/kernels.md, "
           f"{n_lower} lowering statuses match BENCH_lowering.json, "
-          f"{len(obs_namespaces())} counters in sync with "
-          "docs/observability.md")
+          f"{len(obs_namespaces())} counters and "
+          f"{len(literal_tuple(SCOPES_PATH, 'SCOPES'))} scopes in sync "
+          "with docs/observability.md")
     return 0
 
 

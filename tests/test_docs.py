@@ -34,3 +34,13 @@ def test_lowering_artifact_covers_every_backend():
 def test_ast_parse_matches_live_module():
     from repro.kernels.mttkrp import ops as kops
     assert check_docs.ops_backends() == kops.BACKENDS
+
+
+def test_observability_doc_scopes_in_sync():
+    assert check_docs.check_scope_sync() == []
+
+
+def test_scope_literal_matches_live_module():
+    from repro.obs import scopes
+    assert check_docs.literal_tuple(check_docs.SCOPES_PATH, "SCOPES") \
+        == scopes.SCOPES
