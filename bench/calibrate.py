@@ -51,7 +51,6 @@ def side_readings(cell, mesh, seeds, gather_dtype, ref_device, label) -> list:
         t0 = time.perf_counter()
         window = harness.run_window(compiled, job, 0.0)
         sweep_s = time.perf_counter() - t0
-        job.stream = job.factors = None
         values = harness.readings(job, window, ref_device)
         ok, _ = harness.judge(values, cell.limits)
         row = {"side": label, "seed": seed, "sweep_s": sweep_s, "ok": ok,

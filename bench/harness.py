@@ -191,16 +191,23 @@ class Window:
     seconds: float           # start of the window to the end of the last sweep
     sweep_times: list
     fits: list
-    first: tuple             # (factors, lam) after the first sweep, on chip
+    first: tuple             # (factors, lam) after the first sweep, on host
     stream: tuple            # the stream after the last sweep, on chip
 
 
 def run_window(compiled, job: Job, seconds: float) -> Window:
-    """Sweeps back to back; none starts after ``seconds``; one always runs."""
+    """Sweeps back to back; none starts after ``seconds``; one always runs.
+
+    The window takes the stream, factors and lambda off ``job``, and keeps
+    the first sweep's factors for the check on the host, so that a sweep's
+    inputs are freed once it has returned their successors: the device
+    holds the same arrays whether one sweep or many fit in ``seconds``,
+    and ``peak_hbm_gb`` reads the program's peak alone.
+    """
     import jax
 
-    idx, val, mask = job.stream
-    factors, lam = job.factors, job.lam
+    (idx, val, mask), factors, lam = job.stream, job.factors, job.lam
+    job.stream = job.factors = job.lam = None
     times, fits, first = [], [], None
     with jax.profiler.TraceAnnotation(devtrace.WINDOW_SPAN):
         t0 = time.perf_counter()
@@ -214,7 +221,7 @@ def run_window(compiled, job: Job, seconds: float) -> Window:
             t1 = time.perf_counter()
             times.append(t1 - ts)
             if first is None:
-                first = (factors, lam)
+                first = jax.device_get((factors, lam))
             if t1 - t0 >= seconds:
                 break
     return Window(len(times), t1 - t0, times, fits, first, (idx, val, mask))
@@ -433,7 +440,6 @@ def main(argv=None, *, require_tpu: bool = True, t_start: float | None = None,
         if trace_dir:
             shutil.rmtree(trace_dir, ignore_errors=True)
     del compiled
-    job.stream = job.factors = None
     values = readings(job, window, devs[0])
     ok, checks = judge(values, cell.limits)
     for k in sorted(set(values) - set(checks)):

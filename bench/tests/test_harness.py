@@ -68,3 +68,58 @@ def test_without_the_program_exits_nonzero(tmp_path):
                           timeout=300)
     assert proc.returncode != 0
     assert last_json(proc.stdout) is None
+
+
+def test_window_keeps_no_device_array_from_before_it_or_its_first_sweep(
+        tmp_path):
+    """Three sweeps, each taking one second on a stand-in clock. As the
+    third starts, the stream, factors and lambda from before the window
+    and the first sweep's outputs are gone, and the arrays with a
+    dimension (stream, factors, lambda, norm) hold no more bytes than as
+    the first started: ``peak_hbm_gb`` does not grow with the number of
+    sweeps in the window. Scalars are left out: the previous sweep's fit
+    is named until the next sweep returns."""
+    code = """
+import json, types, weakref
+import jax, numpy as np
+cell = harness.load_cell('tiny')
+mesh, devs = harness.open_mesh(cell, '.', require_tpu=False)
+job = harness.prepare(cell, harness.make_inputs(cell, 3000000019), mesh,
+                      cell.traffic['gather_dtype'])
+compiled, _ = harness.compile_sweep(job, cell.traffic['backend'])
+held = [weakref.ref(a) for a in (*job.stream, *job.factors, job.lam)]
+clock = [0.0]
+harness.time = types.SimpleNamespace(perf_counter=lambda: clock[0])
+live, still_held = [], []
+def sweep(*args):
+    live.append(sum(a.nbytes for a in jax.live_arrays() if a.ndim))
+    if len(live) == 3:
+        still_held.append(sum(r() is not None for r in held))
+    out = compiled(*args)
+    if len(live) == 1:
+        held.extend(weakref.ref(a) for a in jax.tree.leaves(out[:3]))
+    clock[0] += 1.0
+    return out
+window = harness.run_window(sweep, job, 3.0)
+values = harness.readings(job, window, devs[0])
+print('RESULT', json.dumps({
+    'sweeps': window.sweeps, 'live': live, 'still_held': still_held,
+    'job_emptied': [x is None for x in (job.stream, job.factors, job.lam)],
+    'first_on_host': all(isinstance(a, np.ndarray)
+                         for a in jax.tree.leaves(window.first)),
+    'stream_mismatch': values['stream_mismatch'],
+    'correct': harness.judge(values, cell.limits)[0]}))
+sys.exit(0)
+"""
+    proc = run_tiny(tiny_root(tmp_path), [], prelude=code)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    (got,) = [json.loads(ln.split(" ", 1)[1])
+              for ln in proc.stdout.splitlines() if ln.startswith("RESULT")]
+    assert got["sweeps"] == 3
+    assert got["still_held"] == [0]
+    first_bytes, _, third_bytes = got["live"]
+    assert third_bytes <= first_bytes, got["live"]
+    assert got["job_emptied"] == [True, True, True]
+    assert got["first_on_host"] is True
+    assert got["stream_mismatch"] == 0
+    assert got["correct"] is True
