@@ -77,14 +77,23 @@ def bucket_by_destination(dest, payload, num_devices: int, bucket_cap: int):
       the number of elements that exceeded capacity (must be 0 when
       ``bucket_cap >= remap_capacity``; exposed for the fault-tolerance
       check).
+
+    Elements keep their stream order inside a bucket (stable sort). Each
+    bucket's run in the sorted stream starts at the sum of the
+    per-destination counts below it, so no search over the stream is made.
     """
     n = dest.shape[0]
     order = jnp.argsort(dest, stable=True)
     dest_s = jnp.take(dest, order)
     payload_s = jnp.take(payload, order, axis=0)
-    # Position of each element inside its destination bucket.
-    start = jnp.searchsorted(dest_s, dest_s, side="left")
-    pos = jnp.arange(n, dtype=dest.dtype) - start.astype(dest.dtype)
+    # Position of each element inside its destination bucket: its sorted
+    # position minus its bucket's start, the count of elements bound for
+    # lower destinations. D is small and static, so the counts are D
+    # reductions and the start an elementwise sum over them.
+    count = [jnp.sum(dest == d, dtype=dest.dtype) for d in range(num_devices)]
+    start = sum(jnp.where(dest_s > d, count[d], 0)
+                for d in range(num_devices))
+    pos = jnp.arange(n, dtype=dest.dtype) - start
     ok = (dest_s < num_devices) & (pos < bucket_cap)
     slot = jnp.where(ok, dest_s * bucket_cap + pos, num_devices * bucket_cap)
     flat = jnp.zeros(

@@ -359,6 +359,10 @@ def build_block_layout(local_row, valid, *, rows_cap: int, blk: int,
       invalid), ``tile_of_block[(n_pad//blk,)]`` non-decreasing output tile
       per block.
 
+    Each tile run starts, in the (sorted or lexsorted) stream, at the
+    exclusive cumsum of the per-tile counts, so an element's rank in its
+    run is its position minus that start: no search over the stream.
+
     Runs under the ``layout`` named scope (:mod:`repro.obs.scopes`), so
     its device time can be read from a trace on every rung that calls it.
     """
@@ -374,30 +378,30 @@ def build_block_layout(local_row, valid, *, rows_cap: int, blk: int,
         padded = ((counts + blk - 1) // blk) * blk
         offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
                                    jnp.cumsum(padded).astype(jnp.int32)])
+        # Run starts: tile t's run begins after every valid element of the
+        # tiles before it. slot = position - start + offset, so one table
+        # of (offset - start) per tile carries both; its last entry (the
+        # invalid elements' tile) is never used.
+        starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                                  jnp.cumsum(counts).astype(jnp.int32)])
         if order_keys:
-            # Rank within the tile run = position under the (tile, keys,
-            # position) lexsort — the jit twin of the host-side
-            # repro.reorder.locality_lexsort (same keys, same tiebreak, so
-            # the two produce bit-identical aligned streams).
+            # Position = rank under the (tile, keys, position) lexsort — the
+            # jit twin of the host-side repro.reorder.locality_lexsort (same
+            # keys, same tiebreak, so the two produce bit-identical aligned
+            # streams). The lexsort orders by tile first, so its tile runs
+            # start at the same ``starts``.
             pos = jnp.arange(cap, dtype=jnp.int32)
             keys = tuple(jnp.asarray(kk).astype(jnp.int32)
                          for kk in order_keys)
             order = jnp.lexsort((pos,) + keys[::-1] + (tile_of_elem,))
-            inv = jnp.zeros(cap, jnp.int32).at[order].set(pos)
-            sorted_tiles = jnp.take(tile_of_elem, order)
-            first_of_tile = jnp.searchsorted(sorted_tiles, tile_of_elem,
-                                             side="left")
-            rank_in_tile = inv - first_of_tile.astype(jnp.int32)
+            position = jnp.zeros(cap, jnp.int32).at[order].set(pos)
         else:
             # Elements sorted by (valid desc, row asc) => per-tile runs
-            # contiguous; rank = distance from the run's first position.
-            first_of_tile = jnp.searchsorted(tile_of_elem, tile_of_elem,
-                                             side="left")
-            rank_in_tile = (jnp.arange(cap, dtype=jnp.int32)
-                            - first_of_tile.astype(jnp.int32))
+            # contiguous, in tile order.
+            position = jnp.arange(cap, dtype=jnp.int32)
         slot = jnp.where(
             valid,
-            jnp.take(offsets, tile_of_elem, fill_value=0) + rank_in_tile,
+            position + jnp.take(offsets - starts, tile_of_elem, fill_value=0),
             n_pad,
         )
         block_start = jnp.arange(n_pad // blk, dtype=jnp.int32) * blk

@@ -130,3 +130,130 @@ def test_build_block_layout_invariants(seed, n_el, tiles, tile_rows, blk,
         assert np.array_equal(s, s[0] + np.arange(len(s)))
     # non-decreasing tile per block
     assert np.all(np.diff(tile_of_block) >= 0)
+
+
+def _layout_oracle(row, valid, rows_cap, blk, tile_rows, order_keys=None):
+    """numpy ``build_block_layout``: run starts by ``np.searchsorted`` over
+    the (lex)sorted tile stream, as ``oocore.planner`` computes them."""
+    cap = row.shape[0]
+    num_tiles = rows_cap // tile_rows
+    n_pad = kops.n_pad_for(cap, rows_cap, blk, tile_rows)
+    tile = np.where(valid, row // tile_rows, num_tiles).astype(np.int64)
+    counts = np.bincount(tile[valid], minlength=num_tiles)[:num_tiles]
+    padded = ((counts + blk - 1) // blk) * blk
+    offsets = np.concatenate([[0], np.cumsum(padded)])
+    pos = np.arange(cap)
+    if order_keys is None:
+        position, sorted_tiles = pos, tile
+    else:
+        order = np.lexsort((pos,) + tuple(order_keys)[::-1] + (tile,))
+        position = np.empty(cap, np.int64)
+        position[order] = pos
+        sorted_tiles = tile[order]
+    first = np.searchsorted(sorted_tiles, tile, side="left")
+    slot = np.where(valid, offsets[tile] + position - first, n_pad)
+    block_start = np.arange(n_pad // blk) * blk
+    tile_of_block = np.clip(
+        np.searchsorted(offsets, block_start, side="right") - 1,
+        0, num_tiles - 1)
+    return slot, tile_of_block
+
+
+def _layout_input(rng, n_el, rows_cap, n_invalid, keyed, tiles_used=None):
+    """A stream as the callers hand it: valid rows ascending (shuffled
+    when ranked by keys), invalid elements trailing."""
+    if tiles_used is None:
+        rows = rng.integers(0, rows_cap, n_el)
+    else:
+        rows = rng.choice(tiles_used, n_el)
+    row = np.sort(rows).astype(np.int32)
+    valid = np.ones(n_el, bool)
+    if n_invalid:
+        valid[-n_invalid:] = False
+    keys = None
+    if keyed:
+        nv = n_el - n_invalid
+        row[:nv] = rng.permutation(row[:nv])
+        keys = (rng.integers(0, 4, n_el).astype(np.int32),
+                rng.integers(0, 64, n_el).astype(np.int32))
+    return row, valid, keys
+
+
+def _assert_layout_matches_oracle(row, valid, keys, rows_cap, blk,
+                                  tile_rows):
+    slot, tile_of_block = kops.build_block_layout(
+        jnp.asarray(row), jnp.asarray(valid), rows_cap=rows_cap, blk=blk,
+        tile_rows=tile_rows,
+        order_keys=None if keys is None else tuple(map(jnp.asarray, keys)))
+    want_slot, want_tob = _layout_oracle(row, valid, rows_cap, blk,
+                                         tile_rows, keys)
+    assert slot.dtype == jnp.int32 and tile_of_block.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(slot), want_slot)
+    np.testing.assert_array_equal(np.asarray(tile_of_block), want_tob)
+
+
+@settings(max_examples=25, deadline=None)
+@given(
+    seed=st.integers(0, 10_000),
+    n_el=st.integers(1, 300),
+    tiles=st.integers(1, 6),
+    tile_rows=st.sampled_from([8, 16, 32]),
+    blk=st.sampled_from([16, 32, 64]),
+    frac_invalid=st.floats(0.0, 1.0),
+    keyed=st.booleans(),
+)
+def test_build_block_layout_matches_searchsorted_oracle(
+        seed, n_el, tiles, tile_rows, blk, frac_invalid, keyed):
+    """Run starts from the per-tile counts give the slots and block tiles
+    a stream-sized binary search gives, bit for bit, on both branches."""
+    rows_cap = tiles * tile_rows
+    rng = np.random.default_rng(seed)
+    row, valid, keys = _layout_input(rng, n_el, rows_cap,
+                                     int(n_el * frac_invalid), keyed)
+    _assert_layout_matches_oracle(row, valid, keys, rows_cap, blk,
+                                  tile_rows)
+
+
+@pytest.mark.parametrize("keyed", [False, True], ids=["sorted", "keyed"])
+@pytest.mark.parametrize("case", ["empty_tiles", "one_tile", "all_invalid",
+                                  "invalid_trailing"])
+def test_build_block_layout_matches_oracle_edge_cases(case, keyed):
+    tile_rows, blk = 8, 16
+    tiles = 1 if case == "one_tile" else 6
+    rows_cap = tiles * tile_rows
+    n_el = 97
+    n_invalid = {"all_invalid": n_el, "invalid_trailing": 40}.get(case, 0)
+    # empty_tiles: rows only in tiles 1 and 4, so tiles 0, 2, 3, 5 are empty
+    used = np.r_[8:16, 32:40] if case == "empty_tiles" else None
+    row, valid, keys = _layout_input(np.random.default_rng(7), n_el,
+                                     rows_cap, n_invalid, keyed, used)
+    _assert_layout_matches_oracle(row, valid, keys, rows_cap, blk,
+                                  tile_rows)
+
+
+@pytest.mark.parametrize("keyed", [False, True], ids=["sorted", "keyed"])
+def test_build_block_layout_lowers_one_search_over_blocks(keyed):
+    """The lowered layout build holds one ``while``: the block→tile search
+    over ``n_pad // blk`` block starts. A search over the stream would add
+    a loop carrying ``(cap,)`` arrays."""
+    import jax
+
+    cap, tiles, tile_rows, blk = 1000, 8, 8, 32
+    rows_cap = tiles * tile_rows
+    n_blocks = kops.n_pad_for(cap, rows_cap, blk, tile_rows) // blk
+    assert n_blocks != cap
+    args = [jax.ShapeDtypeStruct((cap,), jnp.int32),
+            jax.ShapeDtypeStruct((cap,), jnp.bool_)]
+    if keyed:
+        args.append(jax.ShapeDtypeStruct((cap,), jnp.int32))
+
+    def layout(row, valid, *keys):
+        return kops.build_block_layout(row, valid, rows_cap=rows_cap,
+                                       blk=blk, tile_rows=tile_rows,
+                                       order_keys=keys or None)
+
+    text = jax.jit(layout).lower(*args).as_text()
+    loops = [ln for ln in text.splitlines() if "stablehlo.while(" in ln]
+    assert len(loops) == 1, loops
+    assert f"tensor<{n_blocks}xi32>" in loops[0]
+    assert f"tensor<{cap}x" not in loops[0]

@@ -87,3 +87,77 @@ def test_remap_local_oracle_is_pack_mode_and_takes_no_source_layout():
     # arguments.
     assert list(inspect.signature(remap_lib.remap_local).parameters) == \
         ["ft", "to_mode"]
+
+
+def _bucket_oracle(dest, payload, num_devices, bucket_cap):
+    """numpy ``bucket_by_destination``: elements in stream order, each at
+    its destination's next free slot; padding (``dest >= D``) and overflow
+    are left out, overflow counted."""
+    buckets = np.zeros((num_devices, bucket_cap) + payload.shape[1:],
+                       payload.dtype)
+    mask = np.zeros((num_devices, bucket_cap), bool)
+    fill = np.zeros(num_devices, np.int64)
+    dropped = 0
+    for d, row in zip(dest, payload):
+        if d >= num_devices:
+            continue
+        if fill[d] < bucket_cap:
+            buckets[d, fill[d]] = row
+            mask[d, fill[d]] = True
+        else:
+            dropped += 1
+        fill[d] += 1
+    return buckets, mask, dropped
+
+
+def _assert_buckets_match_oracle(dest, payload, num_dev, cap):
+    buckets, mask, dropped = remap_lib.bucket_by_destination(
+        jnp.asarray(dest), jnp.asarray(payload), num_dev, cap)
+    want_b, want_m, want_dropped = _bucket_oracle(dest, payload, num_dev,
+                                                  cap)
+    np.testing.assert_array_equal(np.asarray(buckets), want_b)
+    np.testing.assert_array_equal(np.asarray(mask), want_m)
+    assert int(dropped) == want_dropped
+
+
+@settings(max_examples=30, deadline=None)
+@given(st.integers(0, 10_000), st.integers(1, 6), st.integers(1, 80),
+       st.floats(0.2, 1.5))
+def test_bucket_by_destination_matches_oracle(seed, num_dev, n, fill):
+    """Bucket starts from per-destination counts place every element
+    where the stable stream-order oracle does, bit for bit, with padding
+    and with buckets that fit or overflow."""
+    rng = np.random.default_rng(seed)
+    dest = rng.integers(0, num_dev + 1, n).astype(np.int32)
+    payload = rng.standard_normal((n, 3)).astype(np.float32)
+    cap = max(1, int(fill * n / num_dev))
+    _assert_buckets_match_oracle(dest, payload, num_dev, cap)
+
+
+@pytest.mark.parametrize("overflow", [False, True],
+                         ids=["fits", "overflow"])
+@pytest.mark.parametrize("num_dev", [1, 2, 3, 4, 5, 6])
+def test_bucket_by_destination_matches_oracle_per_device_count(num_dev,
+                                                               overflow):
+    rng = np.random.default_rng(num_dev)
+    n = 120
+    # skewed toward device 0, with ``dest == D`` padding trailing and mixed in
+    dest = np.minimum(rng.geometric(0.4, n) - 1, num_dev).astype(np.int32)
+    dest[-10:] = num_dev
+    payload = np.stack([np.arange(n), rng.integers(-9, 9, n)], 1)\
+        .astype(np.int32)
+    most = int(np.bincount(dest[dest < num_dev]).max())
+    cap = max(1, most // 2) if overflow else most
+    _assert_buckets_match_oracle(dest, payload, num_dev, cap)
+
+
+@pytest.mark.parametrize("num_dev", [1, 4])
+def test_bucket_by_destination_lowers_no_loop(num_dev):
+    """Bucket starts come from counts: the lowered bucketing holds no
+    ``while``, so no binary search over the stream."""
+    n = 1000
+    text = jax.jit(
+        lambda d, p: remap_lib.bucket_by_destination(d, p, num_dev, 300)
+    ).lower(jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n, 3), jnp.float32)).as_text()
+    assert "stablehlo.while" not in text
