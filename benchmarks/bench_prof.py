@@ -35,7 +35,7 @@ from .common import row, write_bench_json
 
 _POINT = GridPoint(nmodes=3, rank=32, blk=32, tile_rows=8, density=1.0)
 # One member per residency family — quick enough under interpret mode.
-_BACKENDS = ("ref", "pallas_fused_gather", "pallas_fused", "pallas")
+_BACKENDS = ("ref", "pallas_fused_gather", "pallas_fused")
 
 
 def _step_rows(quick: bool) -> list[dict]:

@@ -1,18 +1,13 @@
 """Paper Fig. 10: total execution time vs. factor-matrix rank R — plus the
-N-mode fused-vs-materialized kernel comparison.
+N-mode kernel comparisons.
 
 spMTTKRP is memory-bound; traffic ∝ R ⇒ time ≈ linear in R. We measure the
 Dynasor sorted-stream engine across R ∈ {16 … 256} and fit the linearity.
 
-The second half measures what the tentpole dispatch buys: on the 4-mode
-``enron`` tensor, ``pallas_fused`` (Hadamard formed in VMEM) vs. ``pallas``
-(contrib materialized in HBM) across all modes. The materialized path pays
-2·R·4 B/nonzero of extra HBM traffic (contrib write + read); the fused rows
-report that modeled saving alongside measured wall time, and are written to
-``experiments/bench/BENCH_rank.json``.
+Rows are written to ``experiments/bench/BENCH_rank.json``.
 
 The ``gather_in_kernel`` section is the PR-4 tentpole record: on the
-same 4-mode tensor, ``pallas_fused_gather`` (factor matrices resident in
+4-mode ``enron`` tensor, ``pallas_fused_gather`` (factor matrices resident in
 VMEM, gather performed inside the kernel on an int32 index stream) vs.
 ``pallas_fused`` (gathered factor rows materialized in HBM by the
 caller). The counted per-nonzero operand stream drops from
@@ -45,28 +40,6 @@ from .common import (bench_tensor, pr2_static_backend, row, timeit,
                      write_bench_json)
 
 
-def _fused_vs_materialized(t, rank, blk=512, tile_rows=128):
-    """Timed all-mode spMTTKRP through each Pallas backend."""
-    import jax.numpy as jnp
-    rng = np.random.default_rng(0)
-    factors = [jnp.asarray(rng.standard_normal((d, rank)), jnp.float32)
-               for d in t.shape]
-    idx = jnp.asarray(t.indices.astype(np.int32))
-    val = jnp.asarray(t.values.astype(np.float32))
-
-    def make(backend):
-        def run():
-            outs = []
-            for n in range(t.nmodes):
-                outs.append(mttkrp_fused(idx, val, factors, n, t.shape[n],
-                                         blk=blk, tile_rows=tile_rows,
-                                         backend=backend))
-            return outs
-        return run
-
-    return make("pallas_fused"), make("pallas")
-
-
 def run(quick: bool = True, scale: float = 1.0):
     rows = []
     tensors = ("nell-2", "flickr") if quick else (
@@ -87,28 +60,8 @@ def run(quick: bool = True, scale: float = 1.0):
         rows.append(row("rank_fig10", tensor=name, rank="linearity_r",
                         seconds=round(r, 4)))
 
-    # --- 4-mode fused vs materialized (tentpole traffic win) --------------
-    fused_rows = []
+    # --- gather-in-kernel on the 4-mode tensor -----------------------------
     t4 = bench_tensor("enron", scale=0.25 if quick else 1.0)
-    for rank in ((32, 128) if quick else (32, 64, 128, 256)):
-        fused, mat = _fused_vs_materialized(t4, rank)
-        t_f = timeit(fused, warmup=1, iters=2)
-        t_m = timeit(mat, warmup=1, iters=2)
-        # contrib write+read the fused kernel never pays, per mode sweep —
-        # the counted-traffic comparison. Wall times are labeled *_interp_s:
-        # both backends run in the Pallas interpreter on CPU here, so they
-        # measure emulation overhead, not the compiled-kernel HBM win.
-        saved = t4.nmodes * t4.nnz * 2 * rank * 4
-        fused_rows.append(row(
-            "rank_fused_4mode", tensor="enron", nmodes=t4.nmodes,
-            nnz=t4.nnz, rank=rank,
-            fused_interp_s=round(t_f, 5),
-            materialized_interp_s=round(t_m, 5),
-            contrib_traffic_saved_MB=round(saved / 1e6, 3),
-            note="times are interpret-mode emulation; traffic is counted"))
-    rows.extend(fused_rows)
-
-    # --- gather-in-kernel: index stream vs materialized rows --------------
     gather_rows = _gather_in_kernel_rows(t4, quick)
     rows.extend(gather_rows)
 
@@ -200,7 +153,6 @@ def _large_rank_rows(quick: bool) -> list[dict]:
                 gather_dtype=gather_dtype)
 
         t_tiled = timeit(make("pallas_fused_tiled"), warmup=1, iters=2)
-        t_mat = timeit(make("pallas"), warmup=1, iters=2)
         t_bf16 = timeit(make("pallas_fused_tiled", "bfloat16"),
                         warmup=1, iters=2)
         auto = kops.select_backend("auto", nmodes=nmodes, rank=rank,
@@ -214,7 +166,6 @@ def _large_rank_rows(quick: bool) -> list[dict]:
             nnz=t5.nnz, rank=rank, blk=blk, tile_rows=tile_rows,
             rank_slabs=slabs,
             fused_tiled_interp_s=round(t_tiled, 5),
-            materialized_interp_s=round(t_mat, 5),
             bf16_tiled_interp_s=round(t_bf16, 5),
             auto_backend=auto, pr2_auto_backend=pr2,
             contrib_traffic_saved_MB=round(contrib_saved / 1e6, 3),

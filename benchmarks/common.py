@@ -77,7 +77,9 @@ def pr2_static_backend(nmodes: int, rank: int, blk: int,
     VMEM budget, else materialize in HBM. bench_rank and bench_dispatch
     both record this historical decision next to the current one — one
     definition here so the two benches can never disagree about what
-    \"PR-2 behavior\" was.
+    \"PR-2 behavior\" was. ``"pallas"`` names PR 2's materializing
+    backend, since folded into ``pallas_fused``: a label only, never
+    dispatched.
     """
     from repro.kernels.mttkrp import ops as kops
 

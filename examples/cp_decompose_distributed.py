@@ -52,8 +52,7 @@ def main():
     dense4 = np.einsum("ir,jr,kr,lr->ijkl", *facs4)
     idx4 = np.array(list(itertools.product(*map(range, shape4))), np.int32)
     t4 = SparseTensor(idx4, dense4.reshape(-1).astype(np.float32), shape4)
-    ft4 = build_flycoo(t4, D, m_bounds=(2, 8), g_bounds=(8, 64),
-                       fused_gather=True)
+    ft4 = build_flycoo(t4, D, m_bounds=(2, 8), g_bounds=(8, 64))
     res4 = cp_als_distributed(ft4, R4, mesh, iters=15, seed=1,
                               backend="auto")
     rec4 = np.einsum("r,ir,jr,kr,lr->ijkl", res4.lam, *res4.factors)

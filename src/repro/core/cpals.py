@@ -190,8 +190,8 @@ def make_als_sweep(rt: dist.DynasorRuntime, mesh: Mesh, *,
     """One full distributed ALS sweep (all modes, with dynamic remapping).
 
     ``backend`` is the per-device MTTKRP engine: ``segsum`` (plain XLA),
-    ``ref``, ``pallas`` (materialized contrib), ``pallas_fused`` (N-mode
-    fused gather–Hadamard–scatter — works for any tensor order), or
+    ``ref``, ``pallas_fused`` (Khatri-Rao rows formed in XLA, scattered
+    by ``krp_scatter`` — works for any tensor order), or
     ``auto`` (dispatch on mode count / rank padding / VMEM budget; see
     ``kernels.mttkrp.ops.select_backend``).
 

@@ -7,7 +7,7 @@ This is Alg. 2 (Dynasor) on a JAX device mesh:
     FLYCOO row permutation, see ``core.flycoo``);
   * the per-device mode step is gather → Hadamard → segment-scatter
     (``ref``/``segsum`` backends) or the Pallas blocked kernel
-    (``pallas`` materialized / ``pallas_fused`` N-mode fused /
+    (``pallas_fused`` Khatri-Rao rows scattered by ``krp_scatter`` /
     ``pallas_fused_tiled`` rank-slabbed / ``pallas_fused_gather`` and
     its tiled composition, which gather the factor rows *inside* the
     kernel / ``pallas_fused_gather_stream``, the out-of-core variant

@@ -41,20 +41,7 @@ __all__ = [
     "choose_partition_params",
     "build_flycoo",
     "pack_mode",
-    "gather_operand_bytes",
 ]
-
-
-def gather_operand_bytes(nmodes: int, rank: int, g: int,
-                         itemsize: int = 4) -> int:
-    """Bytes of gathered input-factor rows one shard holds resident.
-
-    The N-mode fused kernel streams N−1 gathered ``(g, R)`` factor-row
-    blocks into VMEM per shard (``kernels.mttkrp.kernel.fused_mttkrp_nmode``)
-    instead of one materialized contrib block — this is the extra working-set
-    term Eq. 3 must carry when the fused path is enabled.
-    """
-    return (nmodes - 1) * g * rank * itemsize
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,7 +124,6 @@ def choose_partition_params(
     m_bounds: tuple[int, int] = (1000, 16000),
     g_bounds: tuple[int, int] = (1024, 32768),
     itemsize: int = 4,
-    fused_gather: bool = False,
 ) -> PartitionParams:
     """Pick ``m_n`` and ``g`` per paper Eq. 2 & 3.
 
@@ -146,19 +132,12 @@ def choose_partition_params(
     (output rows + one shard per worker + remap pointers) fits the cache
     budget. α = factor-row bytes, β = nonzero bytes, σ = pointer bytes.
 
-    ``fused_gather=True`` targets the N-mode fused kernel: β additionally
-    carries the N−1 gathered input-factor rows per nonzero
-    (:func:`gather_operand_bytes` / g), shrinking ``g`` so the whole
-    gather-operand block set stays cache/VMEM-resident.
-
     On TPU ``cache_bytes`` is the per-device VMEM budget (≈128 MB on v5e is
     the paper-analogue "total cache"; pass 64 MiB for a single core's view).
     """
     nmodes = len(shape)
     alpha = rank * itemsize
     beta = nmodes * 4 + itemsize        # N int32 coords + value
-    if fused_gather:
-        beta += gather_operand_bytes(nmodes, rank, 1, itemsize)  # per nnz
     sigma = 8                           # remap pointer
     budget = theta * cache_bytes
 
@@ -171,7 +150,12 @@ def choose_partition_params(
             target = int(np.clip(dim // (4 * num_workers), lo, hi))
             q = max(1, round(dim / (num_workers * target)))
             m = math.ceil(dim / (q * num_workers))
-            m = max(1, m)
+            if math.ceil(dim / m) < num_workers:
+                # Rounding m up left fewer super-shards than workers,
+                # which happens at small widths (dim 9 at ν = 8 gave m = 2,
+                # 5 super-shards): take the largest m that still gives
+                # every worker one.
+                m = dim // num_workers
         ms.append(m)
 
     # Choose the largest g in bounds satisfying Eq. 3 for every mode
@@ -302,13 +286,9 @@ def build_flycoo(
     schedule: str = "lpt",
     m_bounds: tuple[int, int] = (1000, 16000),
     g_bounds: tuple[int, int] = (1024, 32768),
-    fused_gather: bool = False,
     ordering: str = "none",
 ) -> FlycooTensor:
     """Preprocess ``t`` into FLYCOO format (paper §V-J stages 1–3).
-
-    ``fused_gather=True`` sizes shards for the N-mode fused kernel's
-    gather-operand working set (see :func:`choose_partition_params`).
 
     ``ordering`` (:data:`repro.reorder.ORDERINGS`) selects the
     locality-aware nonzero ordering :func:`pack_mode` applies within
@@ -327,7 +307,7 @@ def build_flycoo(
             params = choose_partition_params(
                 t.shape, t.nnz, num_workers, rank=rank,
                 cache_bytes=cache_bytes, m_bounds=m_bounds,
-                g_bounds=g_bounds, fused_gather=fused_gather,
+                g_bounds=g_bounds,
             )
         modes = [
             _build_mode(t, n, params.m[n], params.g, num_workers, schedule)

@@ -103,7 +103,7 @@ def mttkrp_fused(indices, values, factors, mode: int, out_rows: int, *,
     Sorts the nonzero stream by output row (the FLYCOO precondition), pads
     the output to a whole number of row tiles, and dispatches through
     ``ops.mttkrp_device_step``'s backend matrix (``docs/kernels.md``) —
-    ``auto`` picks fused vs. rank-tiled fused vs. materialized vs. ref
+    ``auto`` picks the gather family, fused, rank-tiled fused or ref
     from mode count, rank padding and VMEM budget. ``gather_dtype=
     "bfloat16"`` makes the fused family gather bf16 factor rows
     (fp32 accumulate).

@@ -19,26 +19,15 @@ Kernel matrix (see ops.py for the dispatch layer that picks between them):
   ==========================  =============================================
   kernel                      contract
   ==========================  =============================================
-  ``segment_accumulate``      scatter-only: takes an HBM-materialized
-                              ``contrib (B×R)`` block per grid step. Pays
-                              2·R·4 B/nonzero of HBM traffic (write + read
-                              of ``contrib``) that the fused kernels avoid.
-  ``fused_mttkrp_nmode``      gather-Hadamard-scatter for **any** tensor
-                              order: takes N−1 gathered factor-row blocks
-                              and forms ``contrib = val ⊙ ⊙_w rows_w``
-                              entirely in VMEM (loop over input modes inside
-                              the kernel body). ``contrib`` never exists in
-                              HBM.
-  ``fused_mttkrp_nmode_tiled``  the same gather-Hadamard-scatter with a
-                              second grid axis over ``RANK_SLAB``-wide rank
-                              slabs: each grid step holds only one slab of
-                              the N−1 factor blocks / contrib / out tile,
-                              so the VMEM working set is independent of R
-                              and the fused traffic win survives arbitrary
-                              rank (the scalar streams are re-read once per
-                              slab — the only extra cost).
-  ``fused_mttkrp_3mode``      **deprecated alias** (warns): the 3-mode
-                              special case of the N-mode kernel.
+  ``krp_scatter``             scatter-only: takes a block-aligned,
+                              rank-major ``(krp_rows(R), n_pad)`` operand
+                              of Khatri-Rao rows ``val·∏_w A_w[idx_w]``
+                              that XLA formed (ops.py's row-gathering
+                              rungs), 64 B a nonzero at R=16 where a
+                              128-lane row would take 512 B, and widens
+                              each block to the out tile's slab in VMEM.
+                              An optional grid axis over ``RANK_SLAB``
+                              slabs keeps the working set independent of R.
   ``fused_mttkrp_nmode_gather``  gather **inside the kernel**: takes the
                               full replicated factor matrices (VMEM-resident
                               across grid steps) plus a block-aligned
@@ -72,10 +61,11 @@ Kernel matrix (see ops.py for the dispatch layer that picks between them):
                               window (composes with the rank-slab axis).
   ==========================  =============================================
 
-Both fused kernels accept bf16 factor-row operands (``ops.py``'s
-``pallas_fused_bf16`` backend / ``gather_dtype="bfloat16"``): the Hadamard
-product is accumulated in fp32 inside the kernel regardless, so bf16 only
-halves the *gathered-operand* footprint and HBM gather traffic.
+The gather kernels accept bf16 factor matrices (``gather_dtype=
+"bfloat16"``): the Hadamard product is accumulated in fp32 inside the
+kernel regardless, so bf16 only halves the resident footprint and the
+factor-load traffic. ``krp_scatter``'s operand is always fp32; there the
+bf16 gathers happen in XLA.
 
 Grid: one step per nonzero block. ``tile_of_block`` is scalar-prefetched and
 drives the output BlockSpec index_map. The output aliases an ``out_init``
@@ -110,13 +100,11 @@ __all__ = [
     "MXU_RANK_MULTIPLE",
     "RANK_SLAB",
     "FACTOR_ROW_TILE",
-    "segment_accumulate",
-    "fused_mttkrp_nmode",
-    "fused_mttkrp_nmode_tiled",
+    "krp_rows",
+    "krp_scatter",
     "fused_mttkrp_nmode_gather",
     "fused_mttkrp_nmode_gather_tiled",
     "fused_mttkrp_nmode_gather_stream",
-    "fused_mttkrp_3mode",
     "fused_vmem_bytes",
     "fused_tiled_vmem_bytes",
     "gather_vmem_bytes",
@@ -131,7 +119,7 @@ __all__ = [
 # from it rather than re-hardcoding 128.
 MXU_RANK_MULTIPLE = 128
 
-# Width of one rank slab in ``fused_mttkrp_nmode_tiled`` — one MXU lane tile.
+# Width of one rank slab of the rank-tiled kernels — one MXU lane tile.
 RANK_SLAB = MXU_RANK_MULTIPLE
 
 # Row height of one streamed factor tile in the out-of-core gather kernel:
@@ -195,24 +183,25 @@ def fused_vmem_bytes(num_in_modes: int, rank_padded: int, blk: int,
                      tile_rows: int, itemsize: int = 4,
                      gather_itemsize: int | None = None,
                      index_stream_modes: int = 0) -> int:
-    """VMEM working set of one ``fused_mttkrp_nmode`` grid step.
+    """VMEM working set of one grid step of a blocked scatter kernel.
 
-    N−1 gathered factor-row blocks + the in-register ``contrib`` block +
-    the one-hot scatter matrix + the resident output tile + the scalar
-    streams. ops.py's ``auto`` dispatch compares this against the
-    per-core VMEM budget.
+    ``num_in_modes`` operand blocks of ``blk`` rows + the in-register
+    ``contrib`` block + the one-hot scatter matrix + the resident output
+    tile + the scalar streams. The row-gathering rungs' ``krp_scatter``
+    takes one operand block, its Khatri-Rao rows
+    (``planner.KRP_OPERANDS``); the gather kernels take none and add
+    their resident factors (:func:`gather_vmem_bytes`). ops.py's ``auto``
+    dispatch compares this against the per-core VMEM budget.
 
-    ``gather_itemsize`` sizes only the gathered factor-row blocks (2 for
-    the bf16-gather variant); contrib / one-hot / out tile always
-    accumulate at ``itemsize`` (fp32).
+    ``gather_itemsize`` sizes only the operand blocks; contrib / one-hot
+    / out tile always accumulate at ``itemsize`` (fp32).
 
     The scalar-stream term is explicit about its members: the fp32
     values block and the int32 local-row block (both 4 B/element, hence
     ``2·blk·4``), plus — for the gather-in-kernel family, which streams
-    its factor indices instead of pre-gathered rows —
+    its factor indices instead of gathered rows —
     ``index_stream_modes`` int32 index blocks of ``blk`` elements each
-    (``index_stream_modes = N−1``; 0 for the kernels whose operands are
-    already gathered).
+    (``index_stream_modes = N−1``; 0 for ``krp_scatter``).
     """
     gi = itemsize if gather_itemsize is None else gather_itemsize
     factor_blocks = num_in_modes * blk * rank_padded * gi
@@ -227,12 +216,11 @@ def fused_tiled_vmem_bytes(num_in_modes: int, rank_padded: int, blk: int,
                            tile_rows: int, rank_slab: int = RANK_SLAB,
                            itemsize: int = 4,
                            gather_itemsize: int | None = None) -> int:
-    """VMEM working set of one ``fused_mttkrp_nmode_tiled`` grid step.
+    """VMEM working set of one grid step of a rank-slabbed scatter.
 
-    Identical to :func:`fused_vmem_bytes` with the rank axis clamped to one
-    slab — the whole point of the tiled kernel is that this is independent
-    of R, so the fused path never has to fall back to the HBM-materialized
-    kernel on rank growth.
+    Identical to :func:`fused_vmem_bytes` with the rank axis clamped to
+    one slab (``krp_scatter(rank_slab=RANK_SLAB)``, the tiled rung): the
+    working set is independent of R.
     """
     return fused_vmem_bytes(
         num_in_modes, min(rank_padded, rank_slab), blk, tile_rows,
@@ -429,61 +417,104 @@ def _compiler_params():
     return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
+def krp_rows(rank: int) -> int:
+    """Rows of the rank-major operand :func:`krp_scatter` takes for ``rank``.
+
+    Up to one ``RANK_SLAB``, the rank rounded up to a whole sublane tile
+    (8 rows), so R=16 moves 64 B a nonzero instead of a 128-lane row's
+    512 B; above it, whole slabs.
+    """
+    if rank <= RANK_SLAB:
+        return rank + (-rank) % 8
+    return padded_rank(rank)
+
+
+def _krp_scatter_body(tile_ref, row_ref, krp_ref, init_ref, out_ref, *,
+                      tile_rows: int):
+    """Scatter one block of rank-major Khatri-Rao rows into its out tile.
+
+    The ``(rows, blk)`` block is widened with zero rows to the out
+    tile's slab width and turned to ``(blk, slab)`` in VMEM, then
+    scattered exactly as the gather kernels scatter their ``contrib``.
+    """
+    krp = krp_ref[...]
+    slab = out_ref.shape[1]
+    if krp.shape[0] < slab:
+        krp = jnp.concatenate(
+            [krp, jnp.zeros((slab - krp.shape[0], krp.shape[1]), krp.dtype)],
+            axis=0)
+    update = _scatter_update(row_ref[...], krp.T, tile_rows)
+    _accumulate(tile_ref, init_ref, out_ref, update, block_axis=1)
+
+
 @functools.partial(
-    jax.jit, static_argnames=("rows_cap", "blk", "tile_rows", "interpret")
+    jax.jit,
+    static_argnames=("rows_cap", "blk", "tile_rows", "rank_slab",
+                     "interpret"),
 )
-def segment_accumulate(
-    contrib,
+def krp_scatter(
+    krp_t,
     local_row_in_tile,
     tile_of_block,
     *,
     rows_cap: int,
     blk: int = 512,
     tile_rows: int = 128,
+    rank_slab: int | None = None,
     interpret: bool | None = None,
 ):
-    """Blocked segmented accumulation (scatter stage of spMTTKRP).
+    """Blocked scatter of rank-major Khatri-Rao rows (scatter stage of
+    spMTTKRP): ``out[row[i]] += krp_t[:, i]``.
 
     Args:
-      contrib: ``(num_blocks*blk, R)`` block-aligned contributions; padding
-        rows are zero. R should be a multiple of 128 for MXU alignment
-        (ops.py pads).
+      krp_t: ``(krp_rows(R), num_blocks*blk)`` float32, column ``i`` the
+        block-aligned nonzero ``i``'s ``val·∏_w A_w[idx_w]`` (zero rows
+        below R, zero columns at padding slots). Rank-major, so a rank
+        under one slab is not padded to 128 lanes in HBM.
       local_row_in_tile: ``(num_blocks*blk,)`` int32 row *within its tile*
-        (``0 <= r < tile_rows``); padding points at row 0 with zero contrib.
+        (``0 <= r < tile_rows``); padding points at row 0.
       tile_of_block: ``(num_blocks,)`` int32 output tile per block,
         non-decreasing (FLYCOO sort order).
       rows_cap: total output rows (multiple of tile_rows).
+      rank_slab: out-tile columns per pass over the stream; ``None`` is
+        the whole padded rank in one pass. A grid axis over slabs keeps
+        the VMEM working set independent of R.
 
     Returns:
-      ``(rows_cap, R)`` float32 accumulated output.
+      ``(rows_cap, padded_rank(R))`` float32 accumulated output.
     """
     interpret = _resolve_interpret(interpret, blk)
-    n_pad, rank = contrib.shape
+    rows, n_pad = krp_t.shape
+    rpad = padded_rank(rows)
+    slab = rpad if rank_slab is None else rank_slab
     assert n_pad % blk == 0, (n_pad, blk)
     assert rows_cap % tile_rows == 0, (rows_cap, tile_rows)
+    assert rpad % slab == 0 and (rows == rpad or rows <= slab), (rows, slab)
     num_blocks = n_pad // blk
+    krp_block = min(rows, slab)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,              # tile_of_block
-        grid=(num_blocks,),
+        grid=(rpad // slab, num_blocks),
         in_specs=[
-            pl.BlockSpec((blk,), lambda b, tiles: (b,)),          # local_row
-            pl.BlockSpec((blk, rank), lambda b, tiles: (b, 0)),   # contrib
-            pl.BlockSpec((tile_rows, rank),
-                         lambda b, tiles: (tiles[b], 0)),         # out_init alias
+            pl.BlockSpec((blk,), lambda s, b, tiles: (b,)),           # row
+            pl.BlockSpec((krp_block, blk), lambda s, b, tiles: (s, b)),
+            pl.BlockSpec((tile_rows, slab),
+                         lambda s, b, tiles: (tiles[b], s)),          # init
         ],
-        out_specs=pl.BlockSpec((tile_rows, rank),
-                               lambda b, tiles: (tiles[b], 0)),
+        out_specs=pl.BlockSpec((tile_rows, slab),
+                               lambda s, b, tiles: (tiles[b], s)),
     )
-    out_init = jnp.zeros((rows_cap, rank), dtype=jnp.float32)
+    out_init = jnp.zeros((rows_cap, rpad), dtype=jnp.float32)
     return pl.pallas_call(
-        functools.partial(_accum_body_aliased, tile_rows=tile_rows),
+        functools.partial(_krp_scatter_body, tile_rows=tile_rows),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows_cap, rank), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rows_cap, rpad), jnp.float32),
         input_output_aliases={3: 0},        # out_init -> out (indices incl. prefetch)
         interpret=interpret,
         compiler_params=_compiler_params(),
-    )(tile_of_block, local_row_in_tile, contrib, out_init)
+        name="krp_scatter",
+    )(tile_of_block, local_row_in_tile, krp_t, out_init)
 
 
 def _accumulate(tile_ref, init_ref, out_ref, update, *, block_axis: int):
@@ -506,223 +537,16 @@ def _accumulate(tile_ref, init_ref, out_ref, update, *, block_axis: int):
     out_ref[...] += update.astype(out_ref.dtype)
 
 
-def _accum_body_aliased(tile_ref, row_ref, contrib_ref, init_ref, out_ref,
-                        *, tile_rows: int):
-    """Scatter-only body: accumulate one materialized ``contrib`` block."""
-    update = _scatter_update(row_ref[...], contrib_ref[...], tile_rows)
-    _accumulate(tile_ref, init_ref, out_ref, update, block_axis=0)
-
-
-def _fused_nmode_body(*refs, tile_rows: int, block_axis: int):
-    """Fused Hadamard (Alg. 2 lines 19-23) + scatter, any tensor order.
-
-    Ref layout (positional, after scalar prefetch): ``tile_ref, row_ref,
-    val_ref, rows_0 … rows_{K-1}, init_ref, out_ref`` where K = N−1 input
-    modes. ``contrib`` is built by looping ``contrib *= rows_w`` over the
-    gathered factor-row blocks — entirely in VMEM, never in HBM. The
-    factor blocks may be bf16 (the bf16-gather variant); ``contrib``
-    starts fp32 so every product accumulates at fp32.
-
-    The same body serves the untiled and the rank-tiled kernel: the
-    BlockSpecs decide whether a ref covers the full padded rank or one
-    ``RANK_SLAB`` column slab, and the arithmetic is columnwise
-    independent either way.
-    """
-    tile_ref, row_ref, val_ref = refs[0], refs[1], refs[2]
-    factor_refs = refs[3:-2]
-    init_ref, out_ref = refs[-2], refs[-1]
-    rows = row_ref[...]
-    contrib = val_ref[...][:, None].astype(jnp.float32)
-    for rows_w in factor_refs:
-        contrib = contrib * rows_w[...]
-    update = _scatter_update(rows, contrib, tile_rows)
-    _accumulate(tile_ref, init_ref, out_ref, update, block_axis=block_axis)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("rows_cap", "blk", "tile_rows", "interpret")
-)
-def fused_mttkrp_nmode(
-    vals,
-    factor_rows,
-    local_row_in_tile,
-    tile_of_block,
-    *,
-    rows_cap: int,
-    blk: int = 512,
-    tile_rows: int = 128,
-    interpret: bool | None = None,
-    out_init=None,
-):
-    """N-mode fused variant: Hadamard product formed in VMEM, never in HBM.
-
-    Saves 2·R·4 bytes/nonzero of HBM traffic vs. ``segment_accumulate`` on a
-    pre-materialized ``contrib`` (the §Perf memory-term optimization), for a
-    tensor of **any** order.
-
-    Args:
-      vals: ``(num_blocks*blk,)`` block-aligned nonzero values; padding is 0.
-      factor_rows: tuple/list of K = N−1 arrays, each ``(num_blocks*blk, R)``
-        — the gathered input-factor rows per nonzero, block-aligned with
-        ``vals``. R must be identical across operands (a multiple of
-        ``MXU_RANK_MULTIPLE`` for MXU alignment; ops.py pads). fp32 or
-        bf16 — the Hadamard product always accumulates at fp32.
-      local_row_in_tile: ``(num_blocks*blk,)`` int32 row within its tile.
-      tile_of_block: ``(num_blocks,)`` int32 output tile per block,
-        non-decreasing.
-      rows_cap: total output rows (multiple of tile_rows).
-      out_init: optional ``(rows_cap, R)`` float32 accumulator to add
-        into (aliased — the kernel's output starts from it). ``None``
-        means zeros. ``repro.oocore``'s chunked executor threads the
-        running accumulator through here so splitting a stream into
-        chunks reproduces the single-pass accumulation order bit-exactly.
-
-    Returns:
-      ``(rows_cap, R)`` float32 accumulated output.
-    """
-    interpret = _resolve_interpret(interpret, blk)
-    factor_rows = tuple(factor_rows)
-    assert factor_rows, "need at least one input-factor operand"
-    n_pad, rank = factor_rows[0].shape
-    for fr in factor_rows:
-        assert fr.shape == (n_pad, rank), (fr.shape, (n_pad, rank))
-    assert n_pad % blk == 0, (n_pad, blk)
-    assert rows_cap % tile_rows == 0, (rows_cap, tile_rows)
-    num_blocks = n_pad // blk
-    n_in = len(factor_rows)
-
-    in_specs = (
-        [
-            pl.BlockSpec((blk,), lambda b, tiles: (b,)),          # local_row
-            pl.BlockSpec((blk,), lambda b, tiles: (b,)),          # vals
-        ]
-        + [
-            pl.BlockSpec((blk, rank), lambda b, tiles: (b, 0))    # rows_w
-            for _ in range(n_in)
-        ]
-        + [
-            pl.BlockSpec((tile_rows, rank),
-                         lambda b, tiles: (tiles[b], 0)),         # out_init alias
-        ]
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(num_blocks,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((tile_rows, rank),
-                               lambda b, tiles: (tiles[b], 0)),
-    )
-    if out_init is None:
-        out_init = jnp.zeros((rows_cap, rank), dtype=jnp.float32)
-    return pl.pallas_call(
-        functools.partial(_fused_nmode_body, tile_rows=tile_rows,
-                          block_axis=0),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows_cap, rank), jnp.float32),
-        # out_init -> out; operand index counts prefetch + row/val + factors.
-        input_output_aliases={3 + n_in: 0},
-        interpret=interpret,
-        compiler_params=_compiler_params(),
-    )(tile_of_block, local_row_in_tile, vals, *factor_rows, out_init)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("rows_cap", "blk", "tile_rows", "rank_slab",
-                     "interpret"),
-)
-def fused_mttkrp_nmode_tiled(
-    vals,
-    factor_rows,
-    local_row_in_tile,
-    tile_of_block,
-    *,
-    rows_cap: int,
-    blk: int = 512,
-    tile_rows: int = 128,
-    rank_slab: int = RANK_SLAB,
-    interpret: bool | None = None,
-    out_init=None,
-):
-    """Rank-tiled N-mode fused variant: VMEM working set independent of R.
-
-    Same contract as :func:`fused_mttkrp_nmode` with one extra
-    precondition — R must be a multiple of ``rank_slab`` (ops.py's
-    ``pad_rank`` guarantees this; padding columns are zero and sliced
-    off by the caller). The grid gains a *major* axis over rank slabs:
-
-        grid = (R // rank_slab, num_blocks)
-
-    so for each slab the kernel makes a full pass over the nonzero
-    stream, holding only ``(blk, rank_slab)`` factor/contrib blocks and a
-    ``(tile_rows, rank_slab)`` output tile — the working set that made
-    very large R overflow VMEM in the untiled kernel no longer scales
-    with R. The block axis stays *minor* so, within a slab pass, each
-    output tile is still revisited over a contiguous run of blocks (the
-    FLYCOO sort-order invariant the accumulation relies on). Cost of
-    tiling: the scalar streams (values, local rows) are re-read once per
-    slab — ``2·4 B`` per nonzero per slab, negligible against the
-    ``(N−1)·R·4 B`` gather traffic each slab pass moves anyway.
-    """
-    interpret = _resolve_interpret(interpret, blk)
-    factor_rows = tuple(factor_rows)
-    assert factor_rows, "need at least one input-factor operand"
-    n_pad, rank = factor_rows[0].shape
-    for fr in factor_rows:
-        assert fr.shape == (n_pad, rank), (fr.shape, (n_pad, rank))
-    assert n_pad % blk == 0, (n_pad, blk)
-    assert rank % rank_slab == 0, (rank, rank_slab)
-    assert rows_cap % tile_rows == 0, (rows_cap, tile_rows)
-    num_blocks = n_pad // blk
-    num_slabs = rank // rank_slab
-    n_in = len(factor_rows)
-
-    in_specs = (
-        [
-            pl.BlockSpec((blk,), lambda s, b, tiles: (b,)),        # local_row
-            pl.BlockSpec((blk,), lambda s, b, tiles: (b,)),        # vals
-        ]
-        + [
-            pl.BlockSpec((blk, rank_slab),
-                         lambda s, b, tiles: (b, s))               # rows_w
-            for _ in range(n_in)
-        ]
-        + [
-            pl.BlockSpec((tile_rows, rank_slab),
-                         lambda s, b, tiles: (tiles[b], s)),       # out_init
-        ]
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(num_slabs, num_blocks),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((tile_rows, rank_slab),
-                               lambda s, b, tiles: (tiles[b], s)),
-    )
-    if out_init is None:
-        out_init = jnp.zeros((rows_cap, rank), dtype=jnp.float32)
-    return pl.pallas_call(
-        functools.partial(_fused_nmode_body, tile_rows=tile_rows,
-                          block_axis=1),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((rows_cap, rank), jnp.float32),
-        # out_init -> out; operand index counts prefetch + row/val + factors.
-        input_output_aliases={3 + n_in: 0},
-        interpret=interpret,
-        compiler_params=_compiler_params(),
-    )(tile_of_block, local_row_in_tile, vals, *factor_rows, out_init)
-
-
 def _fused_gather_body(*refs, tile_rows: int, onehot_gather: bool,
                        block_axis: int):
     """In-kernel gather + Hadamard + scatter (Alg. 2 lines 13-25 whole).
 
     Ref layout (positional, after scalar prefetch): ``tile_ref, row_ref,
     val_ref, idx_ref, factors_0 … factors_{K-1}, init_ref, out_ref``.
-    Unlike :func:`_fused_nmode_body`, the factor refs here are the
-    (replicated, VMEM-resident) factor *matrices*, not pre-gathered row
-    blocks: each nonzero's rows are formed by :func:`_gather_rows` on
-    its int32 index stream inside the body, so the gathered operands
+    The factor refs are the (replicated, VMEM-resident) factor
+    *matrices*, not gathered row blocks: each nonzero's rows are formed
+    by :func:`_gather_rows` on its int32 index stream inside the body,
+    so the gathered operands
     never touch HBM. ``onehot_gather`` picks the gather form (one-hot
     MXU matmul on the compiled path, ``jnp.take`` in the interpreter —
     bitwise-identical). The factor refs may be bf16 (bf16-gather
@@ -765,10 +589,9 @@ def fused_mttkrp_nmode_gather(
 ):
     """Factor-resident in-kernel gather variant of the fused kernel.
 
-    Where :func:`fused_mttkrp_nmode` receives N−1 HBM-materialized
-    gathered row blocks (``(N−1)·R̂·4`` B written *and* re-read per
-    nonzero by the caller), this kernel receives the replicated factor
-    matrices whole — held in VMEM across every grid step via a
+    Where :func:`krp_scatter` receives Khatri-Rao rows the caller
+    gathered and multiplied in HBM, this kernel receives the replicated
+    factor matrices whole — held in VMEM across every grid step via a
     constant-index BlockSpec — plus a block-aligned int32 index stream,
     and performs the gather in the body. The per-nonzero HBM stream is
     ``(N−1)·4`` B of indices.
@@ -868,7 +691,7 @@ def fused_mttkrp_nmode_gather_tiled(
     Same contract as :func:`fused_mttkrp_nmode_gather` with R required to
     be a multiple of ``rank_slab`` (ops.py's ``pad_rank`` guarantees it).
     The grid gains a *major* axis over rank slabs, exactly like
-    :func:`fused_mttkrp_nmode_tiled`:
+    :func:`krp_scatter`'s with ``rank_slab``:
 
         grid = (R // rank_slab, num_blocks)
 
@@ -1033,7 +856,7 @@ def fused_mttkrp_nmode_gather_stream(
         ``(R // rank_slab, num_blocks)`` — so the window cost is
         independent of R; pass ``rank_slab=R̂`` to disable slabbing).
 
-    ``out_init`` as in :func:`fused_mttkrp_nmode`: the accumulator the
+    ``out_init``: the ``(rows_cap, R)`` float32 accumulator the
     output starts from (``None`` = zeros), which lets the chunked
     executor reproduce single-pass accumulation order bit-exactly.
 
@@ -1106,34 +929,3 @@ def fused_mttkrp_nmode_gather_stream(
         compiler_params=_compiler_params(),
     )(tile_of_block, *tile_schedules, local_row_in_tile, vals, idx_stream,
       *window_operands, out_init)
-
-
-def fused_mttkrp_3mode(
-    vals,
-    rows_a,
-    rows_b,
-    local_row_in_tile,
-    tile_of_block,
-    *,
-    rows_cap: int,
-    blk: int = 512,
-    tile_rows: int = 128,
-    interpret: bool | None = None,
-):
-    """Deprecated alias: the 3-mode special case of the N-mode kernel.
-
-    Kept only for pre-N-mode callers; there is one kernel entry per
-    family and this is not it. Call :func:`fused_mttkrp_nmode` with
-    ``factor_rows=(rows_a, rows_b)`` instead — identical output,
-    bitwise.
-    """
-    import warnings
-
-    warnings.warn(
-        "fused_mttkrp_3mode is a deprecated alias; call "
-        "fused_mttkrp_nmode(vals, (rows_a, rows_b), ...) instead",
-        DeprecationWarning, stacklevel=2)
-    return fused_mttkrp_nmode(
-        vals, (rows_a, rows_b), local_row_in_tile, tile_of_block,
-        rows_cap=rows_cap, blk=blk, tile_rows=tile_rows, interpret=interpret,
-    )

@@ -5,8 +5,8 @@ block-aligned layout the kernel requires (no block straddles an output row
 tile — the runtime equivalent of FLYCOO's shard/super-shard alignment), then
 ``mttkrp_device_step`` runs gather → (fused) Hadamard → blocked scatter.
 
-The runnable backends are the :data:`BACKENDS` tuple (``ref`` / ``pallas``
-/ ``pallas_fused`` / ``pallas_fused_tiled`` / ``pallas_fused_bf16`` /
+The runnable backends are the :data:`BACKENDS` tuple (``ref`` /
+``pallas_fused`` / ``pallas_fused_tiled`` / ``pallas_fused_bf16`` /
 ``pallas_fused_gather`` / ``pallas_fused_gather_tiled`` /
 ``pallas_fused_gather_bf16`` / ``pallas_fused_gather_stream``), plus
 ``auto`` which resolves through :func:`select_backend`. **The full
@@ -16,9 +16,10 @@ working-set formulas, and worked ``auto`` examples — lives in
 table. Short version: ``auto`` picks the cheapest numerics-preserving
 path whose residency the :mod:`repro.oocore.planner` can certify under
 the VMEM budget (in-kernel gather → slab-streamed gather → out-of-core
-row-streamed gather → fused → rank-tiled → materialized, with a
-segment-sum ``ref`` below the MXU-padding rank threshold; the gather
-family needs the factor sizes — ``factor_rows`` — to be considered);
+row-streamed gather → fused → rank-tiled, with a segment-sum ``ref``
+below the MXU-padding rank threshold and where no Pallas rung fits; the
+gather family needs the factor sizes — ``factor_rows`` — to be
+considered);
 the bf16-gather variants (bf16 gathers, fp32 accumulate — halve gather
 traffic, ≈(N−1)·2⁻⁸ rel. error) are opt-in only and never chosen by
 ``auto``.
@@ -58,6 +59,7 @@ __all__ = [
     "STREAM_BACKEND",
     "MIN_MXU_RANK",
     "MXU_RANK_MULTIPLE",
+    "block_sources",
     "build_block_layout",
     "fused_fits_vmem",
     "gather_fits_vmem",
@@ -95,7 +97,6 @@ MIN_MXU_RANK = _kernel.MIN_MXU_RANK
 # neither appears here.
 BACKENDS = (
     "ref",
-    "pallas",
     "pallas_fused",
     "pallas_fused_tiled",
     "pallas_fused_bf16",
@@ -265,8 +266,8 @@ def select_backend(
     slab resident (``pallas_fused_gather_tiled``) → out-of-core tile
     window (``pallas_fused_gather_stream``; factors stay HBM-resident) →
     fused (``pallas_fused``) → rank-tiled fused (``pallas_fused_tiled``)
-    → materialized ``pallas``. Rungs that need the factor sizes are
-    skipped when ``factor_rows`` is ``None``.
+    → ``ref`` where not even one rank slab fits. Rungs that need the
+    factor sizes are skipped when ``factor_rows`` is ``None``.
     """
     # Every resolution emits a ``dispatch.backend`` counter with the
     # decision *and* why (explicit | table | static). select_backend runs
@@ -370,31 +371,21 @@ def build_block_layout(local_row, valid, *, rows_cap: int, blk: int,
         cap = local_row.shape[0]
         num_tiles = rows_cap // tile_rows
         n_pad = n_pad_for(cap, rows_cap, blk, tile_rows)
-
-        tile_of_elem = jnp.where(valid, local_row // tile_rows, num_tiles)
-        counts = jax.ops.segment_sum(
-            valid.astype(jnp.int32), tile_of_elem,
-            num_segments=num_tiles + 1)[:num_tiles]
-        padded = ((counts + blk - 1) // blk) * blk
-        offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                   jnp.cumsum(padded).astype(jnp.int32)])
-        # Run starts: tile t's run begins after every valid element of the
-        # tiles before it. slot = position - start + offset, so one table
-        # of (offset - start) per tile carries both; its last entry (the
+        tile_of_elem, counts, offsets, starts = _tile_runs(
+            local_row, valid, tile_rows=tile_rows, num_tiles=num_tiles,
+            blk=blk)
+        # slot = position - start + offset, so one table of
+        # (offset - start) per tile carries both; its last entry (the
         # invalid elements' tile) is never used.
-        starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                                  jnp.cumsum(counts).astype(jnp.int32)])
         if order_keys:
             # Position = rank under the (tile, keys, position) lexsort — the
             # jit twin of the host-side repro.reorder.locality_lexsort (same
             # keys, same tiebreak, so the two produce bit-identical aligned
             # streams). The lexsort orders by tile first, so its tile runs
             # start at the same ``starts``.
-            pos = jnp.arange(cap, dtype=jnp.int32)
-            keys = tuple(jnp.asarray(kk).astype(jnp.int32)
-                         for kk in order_keys)
-            order = jnp.lexsort((pos,) + keys[::-1] + (tile_of_elem,))
-            position = jnp.zeros(cap, jnp.int32).at[order].set(pos)
+            order = _locality_order(tile_of_elem, order_keys)
+            position = jnp.zeros(cap, jnp.int32).at[order].set(
+                jnp.arange(cap, dtype=jnp.int32))
         else:
             # Elements sorted by (valid desc, row asc) => per-tile runs
             # contiguous, in tile order.
@@ -404,12 +395,99 @@ def build_block_layout(local_row, valid, *, rows_cap: int, blk: int,
             position + jnp.take(offsets - starts, tile_of_elem, fill_value=0),
             n_pad,
         )
-        block_start = jnp.arange(n_pad // blk, dtype=jnp.int32) * blk
-        tile_of_block = jnp.clip(
-            jnp.searchsorted(offsets, block_start, side="right") - 1,
-            0, num_tiles - 1,
-        ).astype(jnp.int32)
-        return slot, tile_of_block
+        return slot, _tile_of_block(offsets, n_pad, blk, num_tiles)
+
+
+def _tile_runs(local_row, valid, *, tile_rows: int, num_tiles: int,
+               blk: int):
+    """Each element's output tile (``num_tiles`` for an invalid one), and
+    per tile its valid-element ``counts``, its first slot in the aligned
+    stream (``offsets``: runs padded to whole blocks) and its run's first
+    position in the sorted stream (``starts``). Both tables have one
+    entry past the last tile."""
+    tile_of_elem = jnp.where(valid, local_row // tile_rows, num_tiles)
+    counts = jax.ops.segment_sum(
+        valid.astype(jnp.int32), tile_of_elem,
+        num_segments=num_tiles + 1)[:num_tiles]
+    padded = ((counts + blk - 1) // blk) * blk
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                               jnp.cumsum(padded).astype(jnp.int32)])
+    # Run starts: tile t's run begins after every valid element of the
+    # tiles before it.
+    starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                              jnp.cumsum(counts).astype(jnp.int32)])
+    return tile_of_elem, counts, offsets, starts
+
+
+def _locality_order(tile_of_elem, order_keys):
+    """Stream positions in (tile, keys, position) lexsort order."""
+    pos = jnp.arange(tile_of_elem.shape[0], dtype=jnp.int32)
+    keys = tuple(jnp.asarray(kk).astype(jnp.int32) for kk in order_keys)
+    return jnp.lexsort((pos,) + keys[::-1] + (tile_of_elem,))
+
+
+def _tile_of_block(offsets, n_pad: int, blk: int, num_tiles: int):
+    """Output tile of each aligned block, non-decreasing."""
+    block_start = jnp.arange(n_pad // blk, dtype=jnp.int32) * blk
+    return jnp.clip(
+        jnp.searchsorted(offsets, block_start, side="right") - 1,
+        0, num_tiles - 1,
+    ).astype(jnp.int32)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("rows_cap", "blk", "tile_rows")
+)
+def block_sources(local_row, valid, *, rows_cap: int, blk: int,
+                  tile_rows: int, order_keys=None):
+    """Where each block of the aligned stream takes its elements from.
+
+    The gather-side twin of :func:`build_block_layout`, same arguments
+    and the same aligned stream: tile t's run of ``counts[t]`` elements,
+    from ``starts[t]`` of the (sorted or lexsorted) stream, fills the
+    slots from ``offsets[t]`` in order. So block ``b`` holds the
+    ``count[b]`` consecutive elements from stream position
+    ``src_start[b]`` and padding after them, and aligning a stream
+    column is one gather of ``blk``-wide windows
+    (:func:`_gather_blocks`), with no per-element scatter.
+
+    Returns ``(order, src_start, count, tile_of_block)``, the last three
+    ``(n_pad // blk,)`` int32. ``order`` is ``None`` without
+    ``order_keys``; with them it is the lexsort order the stream must be
+    taken in before the windows apply.
+
+    Runs under the ``layout`` named scope, as :func:`build_block_layout`.
+    """
+    with jax.named_scope(_scopes.LAYOUT):
+        num_tiles = rows_cap // tile_rows
+        n_pad = n_pad_for(local_row.shape[0], rows_cap, blk, tile_rows)
+        tile_of_elem, counts, offsets, starts = _tile_runs(
+            local_row, valid, tile_rows=tile_rows, num_tiles=num_tiles,
+            blk=blk)
+        order = (_locality_order(tile_of_elem, order_keys)
+                 if order_keys else None)
+        tile_of_block = _tile_of_block(offsets, n_pad, blk, num_tiles)
+        # Blocks past the last run read as the last tile's, with
+        # ``within`` past its count: they hold nothing.
+        within = (jnp.arange(n_pad // blk, dtype=jnp.int32) * blk
+                  - jnp.take(offsets, tile_of_block))
+        src_start = jnp.take(starts, tile_of_block) + within
+        count = jnp.clip(jnp.take(counts, tile_of_block) - within, 0, blk)
+        return order, src_start, count, tile_of_block
+
+
+def _gather_blocks(x, src_start, count, blk: int):
+    """Lay the ``(k, cap)`` stream columns ``x`` down in aligned slots:
+    block ``b`` is ``x[:, src_start[b]:src_start[b] + count[b]]``, zero
+    after. One gather of ``(k, blk)`` windows; ``x`` is padded by one
+    block so no window is clamped. Returns ``(k, n_pad)``."""
+    k = x.shape[0]
+    xp = jnp.pad(x, ((0, 0), (0, blk)))
+    win = jax.vmap(
+        lambda s: jax.lax.dynamic_slice_in_dim(xp, s, blk, axis=1),
+        out_axes=1)(src_start)                          # (k, blocks, blk)
+    keep = jnp.arange(blk, dtype=jnp.int32)[None, :] < count[:, None]
+    return jnp.where(keep[None], win, 0).reshape(k, -1)
 
 
 def _align_to_blocks(x, slot, n_pad: int):
@@ -420,6 +498,49 @@ def _align_to_blocks(x, slot, n_pad: int):
     """
     out_shape = (n_pad + 1,) + x.shape[1:]
     return jnp.zeros(out_shape, x.dtype).at[slot].set(x)[:-1]
+
+
+# Slots per step of the rank-major Khatri-Rao build: its gathered
+# (chunk, R) rows are lane-padded to 128 in HBM, so the build walks the
+# stream in chunks rather than hold N−1 such stream-long arrays at once.
+KRP_CHUNK_SLOTS = 1 << 18
+
+
+def _khatri_rao_rows_t(v_al, idx_al, factors, chunk: int = KRP_CHUNK_SLOTS):
+    """``out[:, s] = v_al[s] · ∏_w factors[w][idx_al[w, s]]`` for each
+    aligned slot ``s``, rank-major.
+
+    ``v_al`` is ``(n_pad,)`` and ``idx_al`` ``(N−1, n_pad)``, laid down in
+    slots (:func:`_gather_blocks`), zero at padding slots. Returns
+    ``(krp_rows(R), n_pad)`` float32 (zero rows below R, zero columns at
+    padding slots), the operand
+    :func:`~repro.kernels.mttkrp.kernel.krp_scatter` takes. The product
+    starts from the fp32 value and multiplies the gathered rows in mode
+    order, as the gather kernels do, so every rung forms the same
+    contributions bit for bit.
+    """
+    n = v_al.shape[0]
+    rank = factors[0].shape[-1]
+    rows = _kernel.krp_rows(rank)
+
+    def block(lo, size):
+        c = jax.lax.dynamic_slice_in_dim(v_al, lo, size)[:, None]
+        for f, ix in zip(factors, idx_al):
+            c = c * jnp.take(f, jax.lax.dynamic_slice_in_dim(ix, lo, size),
+                             axis=0)
+        return jnp.pad(c.T, ((0, rows - rank), (0, 0)))
+
+    if n <= chunk:
+        return block(0, n)
+    full = n // chunk
+    out = jax.lax.fori_loop(
+        0, full,
+        lambda i, acc: jax.lax.dynamic_update_slice_in_dim(
+            acc, block(i * chunk, chunk), i * chunk, axis=1),
+        jnp.zeros((rows, n), jnp.float32))
+    if n % chunk:
+        out = out.at[:, full * chunk:].set(block(full * chunk, n % chunk))
+    return out
 
 
 def tile_schedule(indices_aligned, blk: int, window: int,
@@ -471,20 +592,17 @@ def mttkrp_blocked(contrib, local_row, valid, *, rows_cap: int,
         row = jnp.where(valid, local_row, 0)
         return _ref.segment_accumulate_ref(masked, row, rows_cap)
 
-    n_pad = n_pad_for(local_row.shape[0], rows_cap, blk, tile_rows)
-    slot, tile_of_block = build_block_layout(
-        local_row, valid, rows_cap=rows_cap, blk=blk, tile_rows=tile_rows
-    )
+    _, src_start, count, tile_of_block = block_sources(
+        local_row, valid, rows_cap=rows_cap, blk=blk, tile_rows=tile_rows)
     rank = contrib.shape[-1]
-    contrib_pad = pad_rank(contrib)
-    aligned = _align_to_blocks(
-        jnp.where(valid[:, None], contrib_pad, 0.0), slot, n_pad
-    )
-    row_aligned = _align_to_blocks(
-        (local_row % tile_rows).astype(jnp.int32), slot, n_pad
-    )
-    out = _kernel.segment_accumulate(
-        aligned, row_aligned, tile_of_block,
+    krp_t = _gather_blocks(contrib.astype(jnp.float32).T, src_start, count,
+                           blk)
+    krp_t = jnp.pad(krp_t, ((0, _kernel.krp_rows(rank) - rank), (0, 0)))
+    row_aligned = _gather_blocks(
+        (local_row % tile_rows).astype(jnp.int32)[None], src_start, count,
+        blk)[0]
+    out = _kernel.krp_scatter(
+        krp_t, row_aligned, tile_of_block,
         rows_cap=rows_cap, blk=blk, tile_rows=tile_rows, interpret=interpret,
     )
     return out[:, :rank]
@@ -498,7 +616,7 @@ def mttkrp_blocked(contrib, local_row, valid, *, rows_cap: int,
 def mttkrp_device_step(idx, val, valid, factors, *, mode: int, rows_cap: int,
                        row_offset, blk: int = 512, tile_rows: int = 128,
                        interpret: bool | None = None,
-                       backend: str = "pallas",
+                       backend: str = "pallas_fused",
                        gather_dtype: str = "float32",
                        ordering: str = "none"):
     """Full per-device mode step: gather → Hadamard → blocked scatter.
@@ -519,24 +637,22 @@ def mttkrp_device_step(idx, val, valid, factors, *, mode: int, rows_cap: int,
         compilation (False) for this call.
       backend: one of :data:`BACKENDS` or ``auto`` (decision matrix in
         ``docs/kernels.md``).
-      gather_dtype: ``"float32"`` | ``"bfloat16"`` — dtype the fused
-        family gathers factor rows in (the accumulate is always fp32).
-        ``"bfloat16"`` composes with any fused backend (in-kernel gather
-        included: the resident factor matrices — or the streamed tile
-        windows of ``pallas_fused_gather_stream`` — are held in bf16);
-        the ``pallas_fused_bf16`` / ``pallas_fused_gather_bf16`` backend
-        names are the untiled kernels with this forced on (so a plain
-        backend-string API can reach them). The materialized/``ref``
-        paths ignore it.
+      gather_dtype: ``"float32"`` | ``"bfloat16"`` — dtype every Pallas
+        rung gathers factor rows in (the accumulate is always fp32).
+        In-kernel gathers hold the resident factor matrices — or the
+        streamed tile windows of ``pallas_fused_gather_stream`` — in
+        bf16; the row-gathering rungs take bf16 rows in XLA. The
+        ``pallas_fused_bf16`` / ``pallas_fused_gather_bf16`` backend
+        names are the untiled rungs with this forced on (so a plain
+        backend-string API can reach them). ``ref`` ignores it.
       ordering: :data:`repro.reorder.ORDERINGS` policy. Anything but
         ``"none"`` re-ranks nonzeros *within* each output-row-tile run
         by the gathered modes' factor-tile locality keys (in-jit, via
         ``build_block_layout``'s ``order_keys`` path) before block
         alignment, shrinking the stream backend's per-block tile
-        schedules. Applied to the whole fused/gather family (same
-        aligned stream everywhere ⇒ A/B bit-exactness across backends
-        is preserved per ordering); the materialized/``ref`` paths
-        don't block-align gathered indices, so they ignore it.
+        schedules. Applied to every Pallas rung (same aligned stream
+        everywhere ⇒ A/B bit-exactness across backends is preserved
+        per ordering); ``ref`` does not block-align, so it ignores it.
 
     Returns ``(rows_cap, R)`` float32 local output factor rows.
     """
@@ -563,101 +679,105 @@ def mttkrp_device_step(idx, val, valid, factors, *, mode: int, rows_cap: int,
             backend, gather_dtype = "pallas_fused_gather", "bfloat16"
         local_row = (idx[:, mode] - row_offset).astype(jnp.int32)
         local_row = jnp.where(valid, local_row, 0)
+        if backend == "ref":
+            ell = jnp.where(valid, val, 0.0)[:, None].astype(factors[0].dtype)
+            for w in in_modes:
+                ell = ell * jnp.take(factors[w], idx[:, w], axis=0)
+            return mttkrp_blocked(
+                ell.astype(jnp.float32), local_row, valid, rows_cap=rows_cap,
+                blk=blk, tile_rows=tile_rows, use_ref=True)
 
-        if backend in GATHER_BACKENDS + (STREAM_BACKEND, "pallas_fused",
-                                         "pallas_fused_tiled"):
-            gdt = jnp.bfloat16 if gather_dtype == "bfloat16" else jnp.float32
-            vals = jnp.where(valid, val, 0.0)
-            n_pad = n_pad_for(local_row.shape[0], rows_cap, blk, tile_rows)
-            idx_cols = [jnp.where(valid, idx[:, w], 0).astype(jnp.int32)
-                        for w in in_modes]
-            idx_in = jnp.stack(idx_cols, axis=1)
-            # max_rows is static (factor shapes), so host-side sorts
-            # derive the identical Morton bit budget — and huge modes
-            # widen the key words instead of clamping tile ids.
-            order_keys = _reorder.locality_keys(
-                idx_in, ordering,
-                max_rows=max(factors[w].shape[0] for w in in_modes))
+        gdt = jnp.bfloat16 if gather_dtype == "bfloat16" else jnp.float32
+        vals = jnp.where(valid, val, 0.0)
+        n_pad = n_pad_for(local_row.shape[0], rows_cap, blk, tile_rows)
+        idx_cols = [jnp.where(valid, idx[:, w], 0).astype(jnp.int32)
+                    for w in in_modes]
+        # max_rows is static (factor shapes), so host-side sorts derive the
+        # identical Morton bit budget — and huge modes widen the key words
+        # instead of clamping tile ids.
+        order_keys = _reorder.locality_keys(
+            jnp.stack(idx_cols, axis=1), ordering,
+            max_rows=max(factors[w].shape[0] for w in in_modes))
+        if backend in GATHER_BACKENDS + (STREAM_BACKEND,):
             slot, tile_of_block = build_block_layout(
-                local_row, valid, rows_cap=rows_cap, blk=blk, tile_rows=tile_rows,
-                order_keys=order_keys,
+                local_row, valid, rows_cap=rows_cap, blk=blk,
+                tile_rows=tile_rows, order_keys=order_keys,
             )
+            # In-kernel gather: no per-factor take, no _align_to_blocks of
+            # R-wide rows — only the int32 index stream is block-aligned,
+            # and the replicated factor matrices go to the kernel whole.
+            # Padding/invalid slots point at factor row 0 (in-bounds
+            # gather; their value is 0 so the contribution vanishes).
+            # Casting the resident matrices to the gather dtype is what
+            # halves both the VMEM residency and the factor-load traffic
+            # for bf16 (same values as the row-gathering rungs'
+            # cast-then-take). Mode-major (K, n_pad): each mode's stream
+            # lane-dense.
             v_al = _align_to_blocks(vals, slot, n_pad)
             r_al = _align_to_blocks(
-                (local_row % tile_rows).astype(jnp.int32), slot, n_pad
-            )
-            if backend in GATHER_BACKENDS + (STREAM_BACKEND,):
-                # In-kernel gather: no per-factor take, no _align_to_blocks
-                # of R-wide rows — only the int32 index stream is
-                # block-aligned, and the replicated factor matrices go to
-                # the kernel whole. Padding/invalid slots point at factor
-                # row 0 (in-bounds gather; their value is 0 so the
-                # contribution vanishes). Casting the resident matrices to
-                # the gather dtype is what halves both the VMEM residency
-                # and the factor-load traffic for bf16 (same values as the
-                # materialized path's cast-then-take).
-                # Mode-major (K, n_pad): each mode's stream lane-dense.
-                idx_al = jnp.stack([_align_to_blocks(c, slot, n_pad)
-                                    for c in idx_cols])
-                fmats = tuple(pad_rank(factors[w].astype(gdt))
-                              for w in in_modes)
-                if backend == STREAM_BACKEND:
-                    # Out-of-core: factors stay HBM-resident; the kernel
-                    # streams FACTOR_ROW_TILE-row tiles through a bounded
-                    # VMEM window, driven by the per-block tile schedule.
-                    # Window widths are the planner's static correctness
-                    # bound, so this path is jit-safe for any index data.
-                    frow = _kernel.FACTOR_ROW_TILE
-                    fmats = tuple(_pad_factor_rows(f, frow) for f in fmats)
-                    scheds = tuple(
-                        tile_schedule(
-                            idx_al[i], blk,
-                            _planner.stream_window_tiles(blk, f.shape[0]))
-                        for i, f in enumerate(fmats))
-                    out = _kernel.fused_mttkrp_nmode_gather_stream(
-                        v_al, idx_al, fmats, r_al, tile_of_block, scheds,
-                        rows_cap=rows_cap, blk=blk, tile_rows=tile_rows,
-                        interpret=interpret,
-                    )
-                    return out[:, :rank]
-                kern = (_kernel.fused_mttkrp_nmode_gather_tiled
-                        if backend == "pallas_fused_gather_tiled"
-                        else _kernel.fused_mttkrp_nmode_gather)
-                out = kern(
-                    v_al, idx_al, fmats, r_al, tile_of_block,
+                (local_row % tile_rows).astype(jnp.int32), slot, n_pad)
+            idx_al = jnp.stack([_align_to_blocks(c, slot, n_pad)
+                                for c in idx_cols])
+            fmats = tuple(pad_rank(factors[w].astype(gdt))
+                          for w in in_modes)
+            if backend == STREAM_BACKEND:
+                # Out-of-core: factors stay HBM-resident; the kernel
+                # streams FACTOR_ROW_TILE-row tiles through a bounded
+                # VMEM window, driven by the per-block tile schedule.
+                # Window widths are the planner's static correctness
+                # bound, so this path is jit-safe for any index data.
+                frow = _kernel.FACTOR_ROW_TILE
+                fmats = tuple(_pad_factor_rows(f, frow) for f in fmats)
+                scheds = tuple(
+                    tile_schedule(
+                        idx_al[i], blk,
+                        _planner.stream_window_tiles(blk, f.shape[0]))
+                    for i, f in enumerate(fmats))
+                out = _kernel.fused_mttkrp_nmode_gather_stream(
+                    v_al, idx_al, fmats, r_al, tile_of_block, scheds,
                     rows_cap=rows_cap, blk=blk, tile_rows=tile_rows,
                     interpret=interpret,
                 )
                 return out[:, :rank]
-            # Cast the factor *matrix* before the take so the gather itself
-            # moves gather_dtype-sized rows (the traffic the bf16 variant
-            # halves), not fp32 rows cast afterwards.
-            rows_al = tuple(
-                _align_to_blocks(
-                    pad_rank(jnp.take(factors[w].astype(gdt), idx[:, w], axis=0)),
-                    slot, n_pad
-                )
-                for w in in_modes
-            )
-            kern = (_kernel.fused_mttkrp_nmode_tiled
-                    if backend == "pallas_fused_tiled"
-                    else _kernel.fused_mttkrp_nmode)
+            kern = (_kernel.fused_mttkrp_nmode_gather_tiled
+                    if backend == "pallas_fused_gather_tiled"
+                    else _kernel.fused_mttkrp_nmode_gather)
             out = kern(
-                v_al, rows_al, r_al, tile_of_block,
+                v_al, idx_al, fmats, r_al, tile_of_block,
                 rows_cap=rows_cap, blk=blk, tile_rows=tile_rows,
                 interpret=interpret,
             )
             return out[:, :rank]
 
-        # Materialized path: contrib built in HBM, then blocked scatter.
-        ell = jnp.where(valid, val, 0.0)[:, None].astype(factors[0].dtype)
-        for w in in_modes:
-            ell = ell * jnp.take(factors[w], idx[:, w], axis=0)
-        use_ref = backend == "ref"
-        return mttkrp_blocked(
-            ell.astype(jnp.float32), local_row, valid, rows_cap=rows_cap,
-            blk=blk, tile_rows=tile_rows, interpret=interpret, use_ref=use_ref,
+        # Row-gathering rungs (pallas_fused, pallas_fused_tiled):
+        # each slot's Khatri-Rao row val·∏_w A_w[idx_w] is formed in XLA
+        # — the gather kernels' arithmetic, in their order — and laid
+        # down rank-major, so a mode step holds one (krp_rows(R), n_pad)
+        # operand whatever N is. The kernel only scatters it; the tiled
+        # rung does so one RANK_SLAB of columns per pass. The stream's
+        # scalar columns reach their slots as one gather of block-wide
+        # windows; the factor rows are then taken in slot order.
+        order, src_start, count, tile_of_block = block_sources(
+            local_row, valid, rows_cap=rows_cap, blk=blk,
+            tile_rows=tile_rows, order_keys=order_keys)
+        with jax.named_scope(_scopes.ROW_GATHER):
+            cols = jnp.stack(
+                [jax.lax.bitcast_convert_type(vals.astype(jnp.float32),
+                                              jnp.int32), *idx_cols,
+                 (local_row % tile_rows).astype(jnp.int32)])
+            if order is not None:
+                cols = jnp.take(cols, order, axis=1)
+            al = _gather_blocks(cols, src_start, count, blk)
+            krp_t = _khatri_rao_rows_t(
+                jax.lax.bitcast_convert_type(al[0], jnp.float32), al[1:-1],
+                [factors[w].astype(gdt) for w in in_modes])
+        out = _kernel.krp_scatter(
+            krp_t, al[-1], tile_of_block, rows_cap=rows_cap, blk=blk,
+            tile_rows=tile_rows, interpret=interpret,
+            rank_slab=(_kernel.RANK_SLAB if backend == "pallas_fused_tiled"
+                       else None),
         )
+        return out[:, :rank]
 
     def _attempt(backend: str, interpret):
         # Registered failure boundary (repro.resilience): this is
@@ -700,7 +820,7 @@ def step_traffic_bytes(*, cap: int, nmodes: int, rank: int, rows_cap: int,
 def timed_device_step(idx, val, valid, factors, *, mode: int, rows_cap: int,
                       row_offset, blk: int = 512, tile_rows: int = 128,
                       interpret: bool | None = None,
-                      backend: str = "pallas",
+                      backend: str = "pallas_fused",
                       gather_dtype: str = "float32",
                       ordering: str = "none"):
     """:func:`mttkrp_device_step`, fenced and timed from the host.

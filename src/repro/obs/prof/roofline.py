@@ -45,7 +45,6 @@ RUNG_BY_BACKEND = {
     "pallas_fused": "fused",
     "pallas_fused_bf16": "fused",
     "pallas_fused_tiled": "tiled",
-    "pallas": "materialized",
     "ref": "reference",
     "segsum": "reference",
 }

@@ -7,6 +7,7 @@ inside the program instead. They reach the compiled HLO's
 ``jax.profiler`` trace can be put down to a phase::
 
     jit(inner)/als_mode0/mttkrp/jit(mttkrp_device_step)/jit(build_block_layout)/layout/...
+    jit(inner)/als_mode1/mttkrp/jit(mttkrp_device_step)/row_gather/...
     jit(inner)/als_mode0/solve/...
     jit(inner)/als_mode0/remap/...
     jit(inner)/fit/...
@@ -17,12 +18,13 @@ it with the scope table in ``docs/observability.md``. stdlib only.
 """
 from __future__ import annotations
 
-__all__ = ["FIT", "LAYOUT", "MODE", "MTTKRP", "REMAP", "SCOPES", "SOLVE",
-           "mode_scope"]
+__all__ = ["FIT", "LAYOUT", "MODE", "MTTKRP", "REMAP", "ROW_GATHER", "SCOPES",
+           "SOLVE", "mode_scope"]
 
 # MODE is a prefix: mode n's work runs under ``f"{MODE}{n}"``.
-SCOPES = ("als_mode", "mttkrp", "layout", "solve", "remap", "fit")
-MODE, MTTKRP, LAYOUT, SOLVE, REMAP, FIT = SCOPES
+SCOPES = ("als_mode", "mttkrp", "layout", "solve", "remap", "fit",
+          "row_gather")
+MODE, MTTKRP, LAYOUT, SOLVE, REMAP, FIT, ROW_GATHER = SCOPES
 
 
 def mode_scope(mode: int) -> str:

@@ -2,10 +2,10 @@
 
 The kernels bound their *VMEM* working set, but a mode step still
 materializes its block-aligned operand streams whole: ``O(n_pad)``
-values/rows/indices (plus, for the materializing fused family,
-``O(n_pad·R̂)`` of gathered rows). For nonzero streams that outgrow a
-host/HBM working-set budget this module is the next level of the same
-out-of-core idea: split the FLYCOO stream into **row-tile-aligned
+values/rows/indices (plus, for the row-gathering rungs,
+``O(n_pad·krp_rows(R))`` of Khatri-Rao rows). For nonzero streams that
+outgrow a host/HBM working-set budget this module is the next level of
+the same out-of-core idea: split the FLYCOO stream into **row-tile-aligned
 chunks** of whole nonzero blocks, run every chunk through the same
 kernel, and thread the running accumulator through each call's
 ``out_init`` so the result reproduces the single-pass accumulation

@@ -23,9 +23,9 @@ kernel is chosen. The policies map 1:1 onto the kernel families:
               the out-of-core regime this package adds.
 
 When even streaming cannot be certified (factor sizes unknown, or the
-window itself overflows), the plan degrades through the materializing
-family exactly as the pre-oocore dispatch did: fused → rank-tiled fused
-→ ``pallas``.
+window itself overflows), the plan degrades through the row-gathering
+rungs, fused → rank-tiled fused, and to the segment-sum ``ref`` where
+not even one rank slab fits.
 
 The ladder is *monotone in the budget* by construction: every
 feasibility predicate is ``bytes ≤ budget``, so growing the budget can
@@ -91,6 +91,11 @@ SMEM_BUDGET_BYTES = _kernel.SMEM_BUDGET_BYTES
 # R rounded up to the MXU lane multiple — aliased from kernel.py, the
 # single source shared with ops.py's dispatch arithmetic.
 padded_rank = _kernel.padded_rank
+
+# Operand blocks a row-gathering rung (pallas_fused, pallas_fused_tiled)
+# gives its kernel per grid step: the one rank-major
+# Khatri-Rao block ``krp_scatter`` scatters, whatever the tensor order.
+KRP_OPERANDS = 1
 
 
 def factor_row_tiles(rows: int, frow_tile: int = FACTOR_ROW_TILE) -> int:
@@ -177,12 +182,10 @@ class ResidencyPlan:
     def fits(self) -> bool:
         """Did the chosen backend's working set fit the budget?
 
-        ``pallas`` is the only rung allowed to exceed it (last resort:
-        its block working set is what it is), and ``ref`` never competes
-        for VMEM at all.
+        Every rung the ladder returns does: the last resort is ``ref``,
+        which holds nothing in VMEM.
         """
-        return self.vmem_bytes <= self.vmem_budget \
-            or self.backend in ("pallas", "ref")
+        return self.vmem_bytes <= self.vmem_budget
 
 
 def _normalize_factor_rows(factor_rows, num_in_modes: int):
@@ -217,9 +220,8 @@ def backend_fits(backend: str, *, nmodes: int, rank: int, blk: int,
     ``select_backend`` and filters ``plan_modes``' candidate pool: a
     measured-fast backend whose working set cannot be certified under
     the budget is an extrapolation and must be discarded. Non-Pallas
-    and materializing-last-resort backends (``ref``, ``segsum``,
-    ``pallas``) always "fit" — they manage their own memory. The
-    ``*_bf16`` names fold into ``gather_itemsize=2``.
+    backends (``ref``, ``segsum``) always "fit" — they hold nothing in
+    VMEM. The ``*_bf16`` names fold into ``gather_itemsize=2``.
 
     ``window_tiles`` (streaming rung only) overrides the static
     worst-case per-input-mode window widths with measured/predicted
@@ -236,14 +238,14 @@ def backend_fits(backend: str, *, nmodes: int, rank: int, blk: int,
         backend = backend[:-len("_bf16")]
         gather_itemsize = 2
     k, rpad = nmodes - 1, padded_rank(rank)
+    # The row-gathering rungs hand krp_scatter one fp32 Khatri-Rao operand
+    # block whatever N and the gather dtype are (KRP_OPERANDS).
     if backend == "pallas_fused":
         return _kernel.fused_vmem_bytes(
-            k, rpad, blk, tile_rows,
-            gather_itemsize=gather_itemsize) <= vmem_budget
+            KRP_OPERANDS, rpad, blk, tile_rows) <= vmem_budget
     if backend == "pallas_fused_tiled":
         return _kernel.fused_tiled_vmem_bytes(
-            k, rpad, blk, tile_rows,
-            gather_itemsize=gather_itemsize) <= vmem_budget
+            KRP_OPERANDS, rpad, blk, tile_rows) <= vmem_budget
     per_mode, total = _normalize_factor_rows(factor_rows, k)
     if backend == "pallas_fused_gather":
         return total is not None and _kernel.gather_vmem_bytes(
@@ -269,7 +271,7 @@ def backend_fits(backend: str, *, nmodes: int, rank: int, blk: int,
         return _kernel.gather_stream_vmem_bytes(
             k, rpad, blk, tile_rows, windows,
             gather_itemsize=gather_itemsize) <= vmem_budget
-    # ref / pallas / segsum (and anything dispatched a layer up).
+    # ref / segsum (and anything dispatched a layer up).
     return True
 
 
@@ -316,7 +318,7 @@ def plan_residency(*, nmodes: int, rank: int, blk: int = 512,
          (the out-of-core rung — factors stay in HBM);
       5. fused working set fits    → ``pallas_fused``;
       6. one fused rank slab fits  → ``pallas_fused_tiled``;
-      7. otherwise                 → ``pallas``.
+      7. otherwise                 → ``ref`` (no Pallas rung fits).
 
     Rungs 2–4 need ``factor_rows`` (an int total, or a per-input-mode
     sequence for exact stream windows); without it they are skipped and
@@ -392,16 +394,14 @@ def plan_residency(*, nmodes: int, rank: int, blk: int = 512,
                                        windows=windows))
     if backend_fits("pallas_fused", **kw):
         return finish("pallas_fused",
-                      _kernel.fused_vmem_bytes(k, rpad, blk, tile_rows,
-                                               gather_itemsize=gi))
+                      _kernel.fused_vmem_bytes(KRP_OPERANDS, rpad, blk,
+                                               tile_rows))
     if backend_fits("pallas_fused_tiled", **kw):
         return finish("pallas_fused_tiled",
                       _kernel.fused_tiled_vmem_bytes(
-                          k, rpad, blk, tile_rows, gather_itemsize=gi),
+                          KRP_OPERANDS, rpad, blk, tile_rows),
                       rank_slabs=slabs)
-    return finish("pallas",
-                  _kernel.fused_vmem_bytes(0, rpad, blk, tile_rows,
-                                           gather_itemsize=gi))
+    return finish("ref", 0)
 
 
 # ---------------------------------------------------------------------------

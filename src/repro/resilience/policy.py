@@ -2,8 +2,8 @@
 
 ``repro.oocore.planner`` built the residency ladder (factors whole in
 VMEM → one rank slab → streamed tile window → fused → rank-tiled →
-materialized) for *performance*: pick the fastest rung that fits. This
-module walks the same ladder as a *fallback* structure: when a rung
+segment-sum ``ref``) for *performance*: pick the fastest rung that fits.
+This module walks the same ladder as a *fallback* structure: when a rung
 fails with a resource-class fault (VMEM OOM, failed lowering, an
 injected :class:`~repro.resilience.faults.ResourceFault`), the dispatch
 steps one rung **down** — every lower rung computes the same MTTKRP with
@@ -55,18 +55,16 @@ __all__ = [
 _LOG = logging.getLogger(__name__)
 
 # The dispatch-level degradation ladder, fastest/tightest rung first —
-# the same order ``oocore.planner.plan_residency`` prefers, extended
-# down to the segment-sum reference. Every rung computes the same mode
-# step from the same inputs (the gather family bit-exactly, the
-# fused/materialized/ref rungs up to fp32 accumulation order), so a
-# step down trades only performance, never correctness.
+# the same order ``oocore.planner.plan_residency`` prefers, down to the
+# segment-sum reference. Every rung computes the same mode step from the
+# same inputs, up to fp32 accumulation order, so a step down trades only
+# performance, never correctness.
 DEGRADATION_LADDER = (
     "pallas_fused_gather",
     "pallas_fused_gather_tiled",
     "pallas_fused_gather_stream",
     "pallas_fused",
     "pallas_fused_tiled",
-    "pallas",
     "ref",
 )
 

@@ -7,7 +7,7 @@ replaces both with *measurements*:
 
   * :mod:`repro.tune.microbench` — times every backend (the
     ``kernels.mttkrp.ops.BACKENDS`` family — fused, rank-tiled fused,
-    bf16-gather fused, the in-kernel-gather trio, materialized, ref —
+    bf16-gather fused, the in-kernel-gather trio, ref —
     plus ``segsum``) over a grid of ``(nmodes, rank, blk, tile_rows,
     density)`` on the current host;
   * :mod:`repro.tune.table` — the versioned JSON calibration table

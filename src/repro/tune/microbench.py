@@ -5,7 +5,7 @@ power-law-skewed nonzero stream of the requested density plus random
 factor matrices — timed through every backend:
 
   * the ``kernels.mttkrp.ops.BACKENDS`` family (``pallas_fused``,
-    ``pallas``, ``pallas_fused_tiled``, ``pallas_fused_bf16``, the
+    ``pallas_fused_tiled``, ``pallas_fused_bf16``, the
     in-kernel-gather ``pallas_fused_gather`` trio, the out-of-core
     ``pallas_fused_gather_stream``, ``ref``) via ``mttkrp_device_step``
     (interpret mode on CPU — the timings rank the backends' *emulated*
@@ -143,7 +143,7 @@ def stub_measure(backend: str, point: GridPoint) -> float:
     For schema/CLI smoke runs (``python -m repro.tune calibrate --stub``
     in CI) and anywhere a full interpret-mode calibration is too slow:
     the relative ordering mirrors the counted per-nonzero HBM traffic of
-    each backend (gather < fused < materialized, bf16 halving gather
+    each backend (gather < fused, bf16 halving gather
     bytes, segment-sum paths winning at small rank), so the resulting
     table exercises exactly the production table/model/dispatch code
     paths with self-consistent argmins.
@@ -152,7 +152,6 @@ def stub_measure(backend: str, point: GridPoint) -> float:
     return {
         "ref": 8e-4 * point.rank,
         "segsum": 6e-4 * point.rank,
-        "pallas": 0.05 + 2e-4 * k + 1e-5 * point.blk,
         "pallas_fused": 0.09 + 7e-5 * k + 2e-5 * point.tile_rows,
         "pallas_fused_tiled": 0.095 + 7e-5 * k + 2e-5 * point.tile_rows,
         "pallas_fused_bf16": 0.04 + 4e-5 * k + 2e-5 * point.tile_rows,
@@ -162,7 +161,7 @@ def stub_measure(backend: str, point: GridPoint) -> float:
         "pallas_fused_gather_bf16":
             0.03 + 3e-5 * k + 2e-5 * point.tile_rows,
         # Streaming re-fetches window tiles per block: slower than the
-        # resident gathers, still ahead of the materializing fused path
+        # resident gathers, still ahead of the row-gathering fused path
         # on traffic — mirroring the counted per-nonzero bytes.
         "pallas_fused_gather_stream":
             0.08 + 6e-5 * k + 2e-5 * point.tile_rows + 1e-5 * point.blk,

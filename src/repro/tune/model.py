@@ -218,11 +218,11 @@ def plan_modes(table, ft, rank: int, *,
     callers keep the static configuration.
 
     With ``allowed=None`` the candidate pool is every measured backend
-    *except* the bf16-gather variants — like ``select_backend``'s table
-    path, an automatic planner must not change numerics on timing
-    evidence. Pass ``allowed`` explicitly (e.g.
-    ``table.model.backends``) to let a bf16-opted-in runtime plan with
-    them.
+    in ``AUTO_BACKENDS`` (plus ``segsum``): like ``select_backend``'s
+    table path, an automatic planner must not change numerics on timing
+    evidence, nor follow a backend this module can no longer run.
+    Pass ``allowed`` explicitly (e.g. ``table.model.backends``) to let a
+    bf16-opted-in runtime plan with them.
 
     ``ordering`` (:data:`repro.reorder.ORDERINGS`) is carried verbatim
     into every plan — the locality policy is a numerics-order choice

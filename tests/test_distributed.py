@@ -48,8 +48,9 @@ for n in range(3):
     r = np.asarray(outs[n]); g = np.asarray(outsb[n])
     assert np.abs(g - r).max() / (np.abs(r).max() + 1e-9) < 1e-4
 
-# pallas backend inside shard_map
-fnp = dist.make_spmttkrp_all_modes(rt, mesh, backend="pallas", remap=True)
+# the row-gathering Pallas rung inside shard_map
+fnp = dist.make_spmttkrp_all_modes(rt, mesh, backend="pallas_fused",
+                                   remap=True)
 outsp, _, _ = fnp(idx, val, mask, *factors)
 for n in range(3):
     r = np.asarray(outs[n]); g = np.asarray(outsp[n])
@@ -57,8 +58,7 @@ for n in range(3):
 
 # --- 4-mode fused N-mode kernel end-to-end under shard_map ----------------
 t4 = random_sparse_tensor((20, 15, 12, 10), 400, seed=2)
-ft4 = build_flycoo(t4, 4, m_bounds=(2, 8), g_bounds=(8, 64), cache_bytes=1<<20,
-                   fused_gather=True)
+ft4 = build_flycoo(t4, 4, m_bounds=(2, 8), g_bounds=(8, 64), cache_bytes=1<<20)
 rt4, (idx4, val4, mask4) = dist.prepare_runtime(ft4, rank=8, tile_rows=8)
 f4 = dist.init_factors(ft4, rt4, seed=0)
 perm4 = dist._repad_indices(ft4, ft4.perm_indices.astype(np.int32), rt4.rows_cap)

@@ -295,3 +295,21 @@ def test_build_flycoo_adversarial_corruption(seed):
         val[victim] = np.inf
     with pytest.raises(ValueError):
         build_flycoo(SparseTensor(idx, val, shape), 2)
+
+
+@pytest.mark.parametrize("num_workers", [2, 4, 8])
+def test_partition_params_eq2_every_small_width(num_workers):
+    """Every width the Hypothesis test can draw near ν, checked in full:
+    at least ν super-shards wherever the width exceeds ν."""
+    for dim in range(num_workers + 1, 400):
+        (m,) = choose_partition_params((dim,), nnz=100_000,
+                                       num_workers=num_workers).m
+        assert -(-dim // m) >= num_workers, (dim, m)
+
+
+def test_partition_params_keep_nell2_x4_intervals():
+    """The partitioner's choice for nell-2 on four workers is frozen in
+    the benchmark's nell-2-x4 configuration (its ``intervals``)."""
+    p = choose_partition_params((12092, 9184, 28818), nnz=16_000_000,
+                                num_workers=4)
+    assert p.m == (1008, 1148, 1802)

@@ -105,7 +105,7 @@ def test_gather_with_trailing_invalid():
     val = np.where(valid, val, 0.0).astype(np.float32)
     a = _device_step(idx, val, valid, factors, 0, rows_cap,
                      "pallas_fused_gather")
-    b = _device_step(idx, val, valid, factors, 0, rows_cap, "pallas")
+    b = _device_step(idx, val, valid, factors, 0, rows_cap, "pallas_fused")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-5, atol=1e-5)
 
@@ -219,7 +219,7 @@ def test_auto_without_factor_rows_is_bit_identical_to_pr3():
                                           tiled=True):
                     want = "pallas_fused_tiled"
                 else:
-                    want = "pallas"
+                    want = "ref"
                 assert got == want, kw
 
 
@@ -247,7 +247,7 @@ def test_table_cannot_pick_uncertifiable_gather():
         tune.CalibrationEntry(
             nmodes=3, rank=r, blk=32, tile_rows=8, density=1.0,
             timings_s={"pallas_fused_gather": 0.001, "pallas_fused": 0.5,
-                       "pallas": 1.0, "ref": 1.0}, factor_rows=128)
+                       "ref": 1.0}, factor_rows=128)
         for r in (128, 512)
     ]
     table = tune.CalibrationTable(entries=entries)
@@ -289,8 +289,8 @@ def test_plan_modes_can_choose_gather_and_records_slabs():
     entries = [
         tune.CalibrationEntry(
             nmodes=3, rank=r, blk=32, tile_rows=8, density=1.0,
-            timings_s={"pallas_fused_gather_tiled": 0.001, "pallas": 1.0,
-                       "ref": 1.0}, factor_rows=128)
+            timings_s={"pallas_fused_gather_tiled": 0.001,
+                       "pallas_fused": 1.0, "ref": 1.0}, factor_rows=128)
         for r in (128, 512)
     ]
     plans = tune.plan_modes(tune.CalibrationTable(entries=entries), ft, 512)

@@ -74,7 +74,7 @@ def test_fused_3mode_matches_device_step_ref(seed):
     ref = kops.mttkrp_device_step(jnp.asarray(idx), jnp.asarray(val),
                                   jnp.asarray(valid), factors,
                                   backend="ref", **kw)
-    for backend in ("pallas", "pallas_fused"):
+    for backend in ("pallas_fused", "pallas_fused_tiled"):
         got = kops.mttkrp_device_step(jnp.asarray(idx), jnp.asarray(val),
                                       jnp.asarray(valid), factors,
                                       backend=backend, **kw)
@@ -97,3 +97,75 @@ def test_build_block_layout_invariants():
     tob = np.asarray(tile_of_block)
     # every element's block is tagged with that element's tile
     np.testing.assert_array_equal(tob[blocks], row // tile_rows)
+
+
+@pytest.mark.parametrize("rank,rows", [(1, 8), (8, 8), (16, 16), (20, 24),
+                                       (128, 128), (129, 256), (300, 384)])
+def test_krp_rows_rounds_to_sublanes_then_slabs(rank, rows):
+    """The row-gathering rungs' operand: R to whole 8-row sublane tiles up
+    to one 128-wide slab, whole slabs above it."""
+    from repro.kernels.mttkrp import kernel as kkernel
+    assert kkernel.krp_rows(rank) == rows
+
+
+@pytest.mark.parametrize("chunk", [7, 16, 1 << 20])
+def test_khatri_rao_rows_chunked_build(chunk):
+    """The rank-major operand is the same whether the build walks the
+    slots in chunks (with a ragged tail) or in one step, and padding
+    slots (value 0) come out zero."""
+    rng = np.random.default_rng(3)
+    n_pad, rank, dims = 64, 20, (9, 5, 7)
+    live = rng.random(n_pad) < 0.6
+    vals = np.where(live, rng.standard_normal(n_pad), 0).astype(np.float32)
+    idx = np.stack([np.where(live, rng.integers(0, d, n_pad), 0)
+                    for d in dims]).astype(np.int32)
+    factors = [rng.standard_normal((d, rank)).astype(np.float32)
+               for d in dims]
+    got = np.asarray(kops._khatri_rao_rows_t(
+        jnp.asarray(vals), jnp.asarray(idx),
+        [jnp.asarray(f) for f in factors], chunk=chunk))
+    assert got.shape == (24, n_pad)                 # 20 rounded to 8s
+    want = np.zeros((24, n_pad), np.float32)
+    contrib = vals[:, None]
+    for f, ix in zip(factors, idx):
+        contrib = contrib * f[ix]
+    want[:rank] = contrib.T
+    np.testing.assert_array_equal(got, want)
+    assert not np.any(got[:, ~live])
+
+
+@pytest.mark.parametrize("ordering", ["none", "morton"])
+@pytest.mark.parametrize("rows_cap,tile_rows,blk", [(64, 8, 16),
+                                                    (128, 128, 32)])
+def test_block_sources_gather_equals_slot_scatter(ordering, rows_cap,
+                                                  tile_rows, blk):
+    """Aligning stream columns by block-wide windows
+    (``block_sources`` + ``_gather_blocks``) lays every element in the
+    slot ``build_block_layout`` gives it and zero elsewhere, with the
+    same tile per block, for sorted streams with empty tiles, a ragged
+    last block and invalid elements trailing."""
+    from repro.reorder import locality_keys
+    rng = np.random.default_rng(11)
+    cap, n_valid = 300, 260
+    row = np.sort(rng.integers(0, rows_cap // 2, n_valid) * 2)
+    local_row = np.zeros(cap, np.int32)
+    local_row[:n_valid] = row
+    valid = np.arange(cap) < n_valid
+    idx_in = rng.integers(0, 1000, (cap, 2)).astype(np.int32)
+    keys = locality_keys(jnp.asarray(idx_in), ordering, max_rows=1000) \
+        or None
+    kw = dict(rows_cap=rows_cap, blk=blk, tile_rows=tile_rows)
+    slot, tob = kops.build_block_layout(
+        jnp.asarray(local_row), jnp.asarray(valid), order_keys=keys, **kw)
+    order, src_start, count, tob2 = kops.block_sources(
+        jnp.asarray(local_row), jnp.asarray(valid), order_keys=keys, **kw)
+    np.testing.assert_array_equal(np.asarray(tob2), np.asarray(tob))
+    cols = np.stack([np.arange(cap) + 1, idx_in[:, 0]]).astype(np.int32)
+    x = jnp.asarray(cols)
+    if order is not None:
+        x = jnp.take(x, order, axis=1)
+    got = np.asarray(kops._gather_blocks(x, src_start, count, blk))
+    n_pad = kops.n_pad_for(cap, rows_cap, blk, tile_rows)
+    want = np.asarray(kops._align_to_blocks(
+        jnp.asarray(np.where(valid, cols, 0).T), slot, n_pad)).T
+    np.testing.assert_array_equal(got, want)

@@ -149,13 +149,13 @@ def test_compiled_mode_raises_clear_error():
 def test_compiled_mode_raises_from_kernel_entry():
     # End to end: a kernel call under the compiled mode must fail fast,
     # not silently interpret.
-    contrib = jnp.zeros((128, 128), jnp.float32)
+    krp_t = jnp.zeros((16, 128), jnp.float32)
     rows = jnp.zeros((128,), jnp.int32)
     tiles = jnp.zeros((1,), jnp.int32)
     with execution.execution_mode("compiled"):
         with pytest.raises(execution.ExecutionModeError):
-            kkernel.segment_accumulate(
-                contrib, rows, tiles, rows_cap=8, blk=128, tile_rows=8)
+            kkernel.krp_scatter(
+                krp_t, rows, tiles, rows_cap=8, blk=128, tile_rows=8)
 
 
 def test_explicit_override_beats_mode():

@@ -1,6 +1,6 @@
 """N-mode fused MTTKRP kernel + backend dispatch (golden tests).
 
-Tentpole coverage: ``fused_mttkrp_nmode`` vs. the literal elementwise
+Tentpole coverage: the ``pallas_fused`` rung vs. the literal elementwise
 reference on 2-/3-/4-/5-mode tensors across *all* output modes, the edge
 cases of the blocked layout (empty shards, all-padding blocks, unaligned
 rank, single output tile), and the ``auto`` dispatch decisions.
@@ -13,6 +13,7 @@ from repro.core.mttkrp import mttkrp_elementwise_ref, mttkrp_fused
 from repro.core.tensors import random_sparse_tensor
 from repro.kernels.mttkrp import kernel as kkernel
 from repro.kernels.mttkrp import ops as kops
+from repro.oocore import planner
 from repro.kernels.mttkrp import ref as kref
 
 BLK, TILE = 32, 8
@@ -70,60 +71,73 @@ def test_fused_agrees_with_materialized_pallas(shape):
     rows_cap = -(-shape[0] // TILE) * TILE
     valid = np.arange(len(val)) < len(val) - 7    # trailing invalid
     val = np.where(valid, val, 0.0).astype(np.float32)
+    # Against the XLA segment-sum rung, which forms the same rows and
+    # sums them in another order.
     a = _device_step(idx, val, valid, factors, 0, rows_cap, "pallas_fused")
-    b = _device_step(idx, val, valid, factors, 0, rows_cap, "pallas")
+    b = _device_step(idx, val, valid, factors, 0, rows_cap, "ref")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_fused_kernel_direct_vs_fused_ref():
-    """Kernel-level: hand-built aligned layout, 4-mode, vs the jnp oracle."""
-    rng = np.random.default_rng(5)
-    cap, rows_cap, rank, n_in = 200, 4 * TILE, 128, 3
+def test_device_step_defaults_to_pallas_fused():
+    shape = (12, 10, 8, 6)
+    idx, val, factors = _sorted_case(shape, 250, 24, 0, seed=5)
+    rows_cap = -(-shape[0] // TILE) * TILE
+    valid = np.ones(len(val), bool)
+    default = kops.mttkrp_device_step(
+        jnp.asarray(idx), jnp.asarray(val), jnp.asarray(valid), factors,
+        mode=0, rows_cap=rows_cap, row_offset=0, blk=BLK, tile_rows=TILE,
+        interpret=True)
+    explicit = _device_step(idx, val, valid, factors, 0, rows_cap,
+                            "pallas_fused")
+    np.testing.assert_array_equal(np.asarray(default), np.asarray(explicit))
+
+
+def _krp_case(seed, cap, rows_cap, rank, n_in):
+    """A hand-built aligned layout and its rank-major Khatri-Rao operand."""
+    rng = np.random.default_rng(seed)
     local_row = np.sort(rng.integers(0, rows_cap, cap)).astype(np.int32)
-    valid = jnp.ones(cap, bool)
     vals = rng.standard_normal(cap).astype(np.float32)
     rows_list = [rng.standard_normal((cap, rank)).astype(np.float32)
                  for _ in range(n_in)]
-
-    n_pad = kops.n_pad_for(cap, rows_cap, BLK, TILE)
-    slot, tile_of_block = kops.build_block_layout(
-        jnp.asarray(local_row), valid, rows_cap=rows_cap, blk=BLK,
-        tile_rows=TILE)
-    al = lambda x: jnp.zeros((n_pad + 1,) + x.shape[1:], x.dtype)\
-        .at[slot].set(x)[:-1]
-    out = kkernel.fused_mttkrp_nmode(
-        al(jnp.asarray(vals)), tuple(al(jnp.asarray(r)) for r in rows_list),
-        al(jnp.asarray(local_row % TILE)), tile_of_block,
-        rows_cap=rows_cap, blk=BLK, tile_rows=TILE, interpret=True)
-    ref = kref.fused_mttkrp_ref(jnp.asarray(vals),
-                                [jnp.asarray(r) for r in rows_list],
-                                jnp.asarray(local_row), rows_cap)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-4, atol=1e-4)
-
-
-def test_fused_3mode_wrapper_back_compat():
-    """fused_mttkrp_3mode (kept for callers of the old API) == nmode."""
-    rng = np.random.default_rng(7)
-    cap, rows_cap, rank = 100, 2 * TILE, 128
-    local_row = np.sort(rng.integers(0, rows_cap, cap)).astype(np.int32)
-    vals = rng.standard_normal(cap).astype(np.float32)
-    ra, rb = (rng.standard_normal((cap, rank)).astype(np.float32)
-              for _ in range(2))
     n_pad = kops.n_pad_for(cap, rows_cap, BLK, TILE)
     slot, tile_of_block = kops.build_block_layout(
         jnp.asarray(local_row), jnp.ones(cap, bool), rows_cap=rows_cap,
         blk=BLK, tile_rows=TILE)
     al = lambda x: jnp.zeros((n_pad + 1,) + x.shape[1:], x.dtype)\
         .at[slot].set(x)[:-1]
-    args = (al(jnp.asarray(vals)), al(jnp.asarray(ra)), al(jnp.asarray(rb)),
-            al(jnp.asarray(local_row % TILE)), tile_of_block)
+    contrib = vals[:, None] * np.prod(rows_list, axis=0)
+    krp_t = jnp.pad(al(jnp.asarray(contrib)).T,
+                    ((0, kkernel.krp_rows(rank) - rank), (0, 0)))
+    args = (krp_t, al(jnp.asarray(local_row % TILE)), tile_of_block)
+    ref = kref.fused_mttkrp_ref(jnp.asarray(vals),
+                                [jnp.asarray(r) for r in rows_list],
+                                jnp.asarray(local_row), rows_cap)
+    return args, ref
+
+
+def test_krp_scatter_direct_vs_fused_ref():
+    """Kernel-level: hand-built aligned layout, 4-mode, vs the jnp oracle."""
+    rows_cap, rank = 4 * TILE, 24
+    args, ref = _krp_case(5, 200, rows_cap, rank, 3)
+    assert args[0].shape[0] == 24            # 24 rows: no lane padding
+    out = kkernel.krp_scatter(*args, rows_cap=rows_cap, blk=BLK,
+                              tile_rows=TILE, interpret=True)
+    assert out.shape == (rows_cap, 128)
+    np.testing.assert_allclose(np.asarray(out)[:, :rank], np.asarray(ref),
+                               rtol=1e-4, atol=1e-4)
+    assert not np.any(np.asarray(out)[:, rank:])
+
+
+def test_krp_scatter_slabbed_equals_whole():
+    """A pass per 128-column slab does each column's arithmetic as one
+    pass does: bitwise equal."""
+    rows_cap = 2 * TILE
+    args, _ = _krp_case(7, 100, rows_cap, 256, 2)
     kw = dict(rows_cap=rows_cap, blk=BLK, tile_rows=TILE, interpret=True)
-    out3 = kkernel.fused_mttkrp_3mode(*args, **kw)
-    outn = kkernel.fused_mttkrp_nmode(args[0], (args[1], args[2]), args[3],
-                                      args[4], **kw)
-    np.testing.assert_array_equal(np.asarray(out3), np.asarray(outn))
+    whole = kkernel.krp_scatter(*args, **kw)
+    slabbed = kkernel.krp_scatter(*args, rank_slab=kkernel.RANK_SLAB, **kw)
+    np.testing.assert_array_equal(np.asarray(whole), np.asarray(slabbed))
 
 
 def test_mttkrp_fused_wrapper_matches_ref():
@@ -214,20 +228,24 @@ def test_auto_falls_back_on_tiny_rank():
 
 
 def test_auto_degrades_to_tiled_then_materialized_on_vmem_pressure():
-    # Budget below the full-rank gathered working set, but above one
-    # rank slab: the rank-tiled fused kernel keeps the traffic win.
-    tight = kkernel.fused_vmem_bytes(3, 256, 512, 128) - 1
-    assert kkernel.fused_tiled_vmem_bytes(3, 256, 512, 128) < tight
+    # Budget below the full-rank working set of the one Khatri-Rao
+    # operand, but above one rank slab: the rank-tiled rung.
+    k = planner.KRP_OPERANDS
+    tight = kkernel.fused_vmem_bytes(k, 256, 512, 128) - 1
+    assert kkernel.fused_tiled_vmem_bytes(k, 256, 512, 128) < tight
     assert kops.select_backend("auto", nmodes=4, rank=256,
                                vmem_budget=tight) == "pallas_fused_tiled"
-    # Same rank, fewer input modes -> the untiled kernel fits again.
+    # The kernel takes one operand whatever N is, so fewer input modes
+    # change nothing.
     assert kops.select_backend(
-        "auto", nmodes=2, rank=256, vmem_budget=tight) == "pallas_fused"
-    # Budget below even one slab -> the HBM-materialized path remains
-    # the last resort.
-    tiny = kkernel.fused_tiled_vmem_bytes(3, 256, 512, 128) - 1
+        "auto", nmodes=2, rank=256, vmem_budget=tight) == "pallas_fused_tiled"
+    assert kops.select_backend(
+        "auto", nmodes=2, rank=256, vmem_budget=tight + 1) == "pallas_fused"
+    # Budget below even one slab -> no Pallas rung fits, and the XLA
+    # segment sum, which holds nothing in VMEM, is the last resort.
+    tiny = kkernel.fused_tiled_vmem_bytes(k, 256, 512, 128) - 1
     assert kops.select_backend("auto", nmodes=4, rank=256,
-                               vmem_budget=tiny) == "pallas"
+                               vmem_budget=tiny) == "ref"
 
 
 def test_explicit_backends_pass_through():

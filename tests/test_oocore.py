@@ -224,10 +224,10 @@ def test_residency_plan_invariants(nmodes, rank, blk, tile_rows, rows,
         nmodes=nmodes, rank=rank, blk=blk, tile_rows=tile_rows,
         factor_rows=rows, vmem_budget=budget)
     assert plan.backend in kops.BACKENDS
-    # budget respected (only the materializing last resort may exceed)
+    # budget respected: every rung fits, the last resort (ref) holds
+    # nothing in VMEM
     assert plan.fits
-    if plan.backend not in ("pallas", "ref"):
-        assert plan.vmem_bytes <= budget
+    assert plan.vmem_bytes <= budget
     # every factor row covered exactly once by the tile spans
     for f in plan.factors:
         spans = f.tile_spans()
@@ -240,6 +240,21 @@ def test_residency_plan_invariants(nmodes, rank, blk, tile_rows, rows,
             assert f.window_tiles < f.row_tiles
     if plan.streams:
         assert plan.window_tiles and len(plan.window_tiles) == nmodes - 1
+
+
+@pytest.mark.parametrize("nmodes", [3, 4, 5])
+def test_residency_ladder_ends_in_ref(nmodes):
+    """Below one rank slab of the tiled rung no Pallas rung fits: the
+    plan is the XLA segment sum, which holds nothing in VMEM. One byte
+    more and the tiled rung is back."""
+    slab = kkernel.fused_tiled_vmem_bytes(planner.KRP_OPERANDS, 256, 512,
+                                          128)
+    kw = dict(nmodes=nmodes, rank=256, blk=512, tile_rows=128)
+    plan = planner.plan_residency(vmem_budget=slab - 1, **kw)
+    assert (plan.backend, plan.vmem_bytes, plan.fits) == ("ref", 0, True)
+    plan = planner.plan_residency(vmem_budget=slab, **kw)
+    assert (plan.backend, plan.vmem_bytes, plan.fits) == (
+        "pallas_fused_tiled", slab, True)
 
 
 @settings(max_examples=20, deadline=None)
@@ -255,9 +270,10 @@ def test_residency_plan_monotone_in_budget(nmodes, rank, blk, rows, b1, b2):
     """Growing the budget may only move the decision toward earlier
     (more-resident) rungs of the ladder — never the reverse."""
     lo, hi = sorted((b1, b2))
-    order = ["ref", "pallas_fused_gather", "pallas_fused_gather_tiled",
+    # rank >= MIN_MXU_RANK here, so ref is only the last resort
+    order = ["pallas_fused_gather", "pallas_fused_gather_tiled",
              planner.STREAM_BACKEND, "pallas_fused", "pallas_fused_tiled",
-             "pallas"]
+             "ref"]
     p_lo = planner.plan_residency(nmodes=nmodes, rank=rank, blk=blk,
                                   tile_rows=8, factor_rows=rows,
                                   vmem_budget=lo << 20)
@@ -354,8 +370,9 @@ def test_plan_modes_can_choose_stream_and_records_geometry():
     entries = [
         tune.CalibrationEntry(
             nmodes=3, rank=r, blk=32, tile_rows=8, density=1.0,
-            timings_s={"pallas_fused_gather_stream": 0.001, "pallas": 1.0,
-                       "ref": 1.0}, factor_rows=128, stream_window_tiles=1)
+            timings_s={"pallas_fused_gather_stream": 0.001,
+                       "pallas_fused": 1.0, "ref": 1.0},
+            factor_rows=128, stream_window_tiles=1)
         for r in (128, 512)
     ]
     plans = tune.plan_modes(tune.CalibrationTable(entries=entries), ft, 512)
@@ -400,26 +417,3 @@ def test_v4_round_trip_records_stream_fields(tmp_path):
     assert loaded.entries == table.entries
     assert loaded.schema_version == 4
 
-
-# ---------------------------------------------------------------------------
-# Legacy alias
-# ---------------------------------------------------------------------------
-
-def test_fused_mttkrp_3mode_is_deprecated_alias():
-    rng = np.random.default_rng(0)
-    blk, tile = 16, 8
-    n_pad, rank, rows_cap = 32, 128, 16
-    vals = jnp.asarray(rng.standard_normal(n_pad), jnp.float32)
-    ra = jnp.asarray(rng.standard_normal((n_pad, rank)), jnp.float32)
-    rb = jnp.asarray(rng.standard_normal((n_pad, rank)), jnp.float32)
-    rows = jnp.asarray(rng.integers(0, tile, n_pad), jnp.int32)
-    tiles = jnp.asarray(np.sort(rng.integers(0, rows_cap // tile,
-                                             n_pad // blk)), jnp.int32)
-    with pytest.warns(DeprecationWarning, match="deprecated alias"):
-        old = kkernel.fused_mttkrp_3mode(
-            vals, ra, rb, rows, tiles, rows_cap=rows_cap, blk=blk,
-            tile_rows=tile)
-    new = kkernel.fused_mttkrp_nmode(
-        vals, (ra, rb), rows, tiles, rows_cap=rows_cap, blk=blk,
-        tile_rows=tile)
-    np.testing.assert_array_equal(np.asarray(old), np.asarray(new))

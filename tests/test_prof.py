@@ -329,7 +329,7 @@ def test_roofline_groups_and_skips_byteless_spans():
              self_counters={"ops.step.model_bytes": 100}),
         _rec(1, -1, "step", 1.0, 3.0, args={"backend": "ref"},
              self_counters={"ops.step.model_bytes": 300}),
-        _rec(2, -1, "step", 3.0, 4.0, args={"backend": "pallas"},
+        _rec(2, -1, "step", 3.0, 4.0, args={"backend": "pallas_fused"},
              self_counters={"ops.step.model_bytes": 100}),
         _rec(3, -1, "solve", 4.0, 5.0),        # no bytes: no row
     ]

@@ -88,10 +88,11 @@ def test_tiled_kernel_direct_multi_slab():
         blk=BLK, tile_rows=TILE)
     al = lambda x: jnp.zeros((n_pad + 1,) + x.shape[1:], x.dtype)\
         .at[slot].set(x)[:-1]
-    out = kkernel.fused_mttkrp_nmode_tiled(
-        al(jnp.asarray(vals)), tuple(al(jnp.asarray(r)) for r in rows_list),
-        al(jnp.asarray(local_row % TILE)), tile_of_block,
-        rows_cap=rows_cap, blk=BLK, tile_rows=TILE, interpret=True)
+    contrib = vals[:, None] * np.prod(rows_list, axis=0)
+    out = kkernel.krp_scatter(
+        al(jnp.asarray(contrib)).T, al(jnp.asarray(local_row % TILE)),
+        tile_of_block, rows_cap=rows_cap, blk=BLK, tile_rows=TILE,
+        rank_slab=kkernel.RANK_SLAB, interpret=True)
     ref = kref.fused_mttkrp_ref(jnp.asarray(vals),
                                 [jnp.asarray(r) for r in rows_list],
                                 jnp.asarray(local_row), rows_cap)
@@ -107,7 +108,7 @@ def test_tiled_with_trailing_invalid_matches_materialized():
     val = np.where(valid, val, 0.0).astype(np.float32)
     a = _device_step(idx, val, valid, factors, 0, rows_cap,
                      "pallas_fused_tiled")
-    b = _device_step(idx, val, valid, factors, 0, rows_cap, "pallas")
+    b = _device_step(idx, val, valid, factors, 0, rows_cap, "pallas_fused")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=1e-5, atol=1e-5)
 
@@ -202,8 +203,8 @@ def test_large_rank_no_longer_falls_back_to_materialized():
     # Configurations the PR-2 static rule sent to `pallas` purely on
     # VMEM grounds (full-rank fused working set > budget): the slabbed
     # working set fits, so the dispatch keeps a fused variant.
-    for nmodes, rank, blk in [(5, 8192, 512), (5, 2048, 2048),
-                              (4, 4096, 2048)]:
+    for nmodes, rank, blk in [(5, 16384, 512), (5, 4096, 2048),
+                              (4, 8192, 2048)]:
         assert not kops.fused_fits_vmem(nmodes, rank, blk, 128)
         got = kops.select_backend("auto", nmodes=nmodes, rank=rank,
                                   blk=blk, tile_rows=128)
@@ -234,7 +235,7 @@ def _tiled_loving_table(rank_knots=(128, 512)):
     entries = [
         tune.CalibrationEntry(
             nmodes=3, rank=r, blk=32, tile_rows=8, density=1.0,
-            timings_s={"pallas_fused_tiled": 0.001, "pallas": 1.0,
+            timings_s={"pallas_fused_tiled": 0.001, "pallas_fused": 1.0,
                        "ref": 1.0})
         for r in rank_knots
     ]
@@ -286,7 +287,7 @@ def test_plan_for_rederives_rank_slabs():
         i_pad=(8, 8, 8), nnz_cap=8, bucket_cap=8, shape=(8, 8, 8),
         mode_plans=(dist.ModePlan("pallas_fused_tiled", 32, 8, 4),) * 3)
     # explicit non-tiled override must not carry the tuned plan's slabs
-    assert tuned.plan_for(0, "pallas").rank_slabs == 1
+    assert tuned.plan_for(0, "pallas_fused").rank_slabs == 1
     assert tuned.plan_for(0, "auto").rank_slabs == 4
     # explicit tiled backend on an untuned runtime gets the real count
     untuned = dist.DynasorRuntime(

@@ -171,6 +171,27 @@ def test_ladder_is_real_backends_and_strictly_descending():
     assert next_rung("not_a_backend") is None
 
 
+@pytest.mark.parametrize("rank", [128, 1024, 16384])
+def test_ladder_step_down_fits_wherever_the_rung_above_fits(rank):
+    """From the row-gathering rungs down, each rung fits every VMEM
+    budget the rung above it fits, so a fallback never lands on a rung
+    that needs more VMEM than the one it left."""
+    from repro.kernels.mttkrp import kernel as kkernel
+    from repro.oocore import planner
+    walk = DEGRADATION_LADDER[DEGRADATION_LADDER.index("pallas_fused"):]
+    assert walk == ("pallas_fused", "pallas_fused_tiled", "ref")
+    rpad = planner.padded_rank(rank)
+    edges = [kkernel.fused_vmem_bytes(planner.KRP_OPERANDS, rpad, 512, 128),
+             kkernel.fused_tiled_vmem_bytes(planner.KRP_OPERANDS, rpad,
+                                            512, 128)]
+    kw = dict(nmodes=5, rank=rank, blk=512, tile_rows=128)
+    for budget in [e + d for e in edges for d in (-1, 0, 1)] + [0]:
+        fits = [planner.backend_fits(b, vmem_budget=budget, **kw)
+                for b in walk]
+        assert fits == sorted(fits), (budget, fits)   # False… then True…
+        assert fits[-1]
+
+
 def test_retry_run_recovers_and_counts():
     calls = {"n": 0}
 
@@ -294,11 +315,12 @@ def test_dispatch_exhausts_at_ladder_floor():
         [ResourceFault("ops.kernel", i) for i in range(20)])
     with ocnt.use_registry() as reg:
         with pytest.raises(ResilienceExhausted):
-            RetryPolicy().dispatch(call, "pallas", True)
-    # pallas → ref → floor: two attempts, one recorded degradation.
-    assert [b for b, _ in log] == ["pallas", "ref"]
+            RetryPolicy().dispatch(call, "pallas_fused_tiled", True)
+    # pallas_fused_tiled → ref → floor: two attempts, one recorded
+    # degradation.
+    assert [b for b, _ in log] == ["pallas_fused_tiled", "ref"]
     assert reg.get("resilience.degradations",
-                   **{"from": "pallas", "to": "ref"}) == 1
+                   **{"from": "pallas_fused_tiled", "to": "ref"}) == 1
 
 
 def test_dispatch_execution_mode_error_flip_then_raise():
@@ -427,7 +449,7 @@ def test_checkpoint_restore_empty_dir_is_fresh_start(tmp_path, rng):
 
 @pytest.mark.parametrize("mutate, match", [
     (dict(rank=5), "rank"),
-    (dict(backend="pallas"), "backend"),
+    (dict(backend="pallas_fused"), "backend"),
     (dict(ordering="morton"), "ordering"),
 ])
 def test_checkpoint_restore_rejects_config_mismatch(tmp_path, rng, mutate,

@@ -31,15 +31,15 @@ RANK = 16
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
-def _compiled_op_names(shape, nnz=1500):
+def _compiled_op_names(shape, nnz=1500, backend="auto"):
     """``op_name``s of the sweep compiled as the benchmark compiles it:
-    ahead of time, on one device, ``backend="auto"``."""
+    ahead of time, on one device, ``backend="auto"`` by default."""
     t = random_sparse_tensor(shape, nnz, seed=3)
     ft = build_flycoo(t, num_workers=1)
     rt, stream = dist.prepare_runtime(ft, RANK)
     mesh = Mesh(np.array(jax.devices()[:1]), (dist.AXIS,))
     factors = [np.zeros((p, RANK), np.float32) for p in rt.i_pad]
-    compiled = make_als_sweep(rt, mesh, backend="auto").lower(
+    compiled = make_als_sweep(rt, mesh, backend=backend).lower(
         *stream, np.ones(1, np.float32), *factors,
         np.ones(RANK, np.float32), np.bool_(True)).compile()
     return set(_OP_NAME.findall(compiled.as_text()))
@@ -59,6 +59,21 @@ def test_sweep_phases_reach_compiled_metadata(shape):
     assert any(re.search(rf"(^|/){scopes.FIT}/", s) for s in names)
     # No mode scope past the tensor's order.
     assert not any(scopes.mode_scope(len(shape)) + "/" in s for s in names)
+
+
+@pytest.mark.parametrize("backend,scoped", [("pallas_fused", True),
+                                            ("auto", False)])
+def test_row_gather_scope_only_on_row_gathering_rungs(backend, scoped):
+    """The row-gathering rung's operand build runs under ``row_gather``,
+    inside its mode's ``mttkrp``; the resident gather rung, which ``auto``
+    picks at these widths, has none."""
+    shape = (12, 9, 15, 7)
+    names = _compiled_op_names(shape, backend=backend)
+    for n in range(len(shape)):
+        row_gather = re.compile(rf"(^|/){scopes.mode_scope(n)}/"
+                                rf"{scopes.MTTKRP}/(.*/)?"
+                                rf"{scopes.ROW_GATHER}/")
+        assert any(row_gather.search(s) for s in names) == scoped, n
 
 
 def test_tracer_span_lands_on_profiler_host_plane(tmp_path):

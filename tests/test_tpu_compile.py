@@ -110,3 +110,33 @@ def test_stream_rung_compiles_or_is_refused(rows, nnz_cap, admitted,
     if fits:
         compiled = _compile(kops.STREAM_BACKEND, 0, nnz_cap, one_chip, rows)
         assert "tpu_custom_call" in compiled.as_text()
+
+
+# A 5-mode mode step on the row-gathering rung, at vast-2015-mc1-5d's
+# published widths: output mode 4 (89 rows, 12 tiles of 8), whose four
+# input factors include mode 0's 165,427 rows, which no VMEM holds.
+VAST = (165427, 11374, 2, 100, 89)
+VAST_ROWS = tuple(-(-d // TILE_ROWS) * TILE_ROWS for d in VAST)
+# Temp bytes a nonzero may take. Four input factor rows each padded to 128
+# fp32 lanes take 4 × 512 = 2048 B a nonzero before the kernel reads one;
+# the rung keeps one rank-major Khatri-Rao row instead (64 B at R=16),
+# beside the stream's aligned scalars and the chunked gather's fixed
+# (KRP_CHUNK_SLOTS · 4 · 512 B, ~0.5 GB) working set.
+VAST_TEMP_PER_NNZ = 1024
+
+
+def test_vast_fused_step_temp_per_nonzero(one_chip, no_persistent_cache):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    mode = 4
+    compiled = kops.mttkrp_device_step.trace(
+        sds((NNZ_CAP, 5), jnp.int32), sds((NNZ_CAP,), jnp.float32),
+        sds((NNZ_CAP,), jnp.bool_),
+        [sds((r, RANK), jnp.float32) for r in VAST_ROWS], mode=mode,
+        rows_cap=VAST_ROWS[mode], row_offset=sds((), jnp.int32), blk=BLK,
+        tile_rows=TILE_ROWS, interpret=False, backend="pallas_fused",
+    ).lower(lowering_platforms=("tpu",)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < VAST_TEMP_PER_NNZ * NNZ_CAP, temp / NNZ_CAP

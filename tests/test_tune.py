@@ -17,6 +17,7 @@ from repro.core.remap import remap_capacities
 from repro.core.tensors import random_sparse_tensor, zipf_4d
 from repro.kernels.mttkrp import kernel as kkernel
 from repro.kernels.mttkrp import ops as kops
+from repro.oocore.planner import KRP_OPERANDS
 from repro.tune.microbench import BACKENDS, GridPoint
 from repro.tune.table import (SCHEMA_VERSION, CalibrationTable,
                               SchemaVersionError)
@@ -94,13 +95,13 @@ def test_find_table_never_serves_stub_tables(table, tmp_path):
 
 
 def test_model_cache_invalidated_on_entry_change():
-    t = _table_with_ranks((16,), lambda r: {"pallas": 0.5, "ref": 0.1})
+    t = _table_with_ranks((16,), lambda r: {"pallas_fused": 0.5, "ref": 0.1})
     kw = dict(nmodes=3, rank=16, blk=32, tile_rows=8)
     assert t.best_backend(**kw) == "ref"      # builds + caches the model
     t.entries.append(tune.CalibrationEntry(
         nmodes=3, rank=16, blk=32, tile_rows=8, density=4.0,
-        timings_s={"pallas": 0.01, "ref": 0.9}))
-    assert t.best_backend(**kw) == "pallas"   # cache rebuilt, not stale
+        timings_s={"pallas_fused": 0.01, "ref": 0.9}))
+    assert t.best_backend(**kw) == "pallas_fused"  # cache rebuilt
 
 
 def test_find_table_registry(table, tmp_path):
@@ -132,21 +133,22 @@ def _table_with_ranks(ranks, timings_fn):
 def test_interpolation_at_off_grid_rank():
     # times linear in log2(rank) -> piecewise-linear interp is exact
     t = _table_with_ranks(
-        (16, 64), lambda r: {"pallas": 0.01 * np.log2(r),
+        (16, 64), lambda r: {"pallas_fused": 0.01 * np.log2(r),
                              "ref": 0.08 - 0.01 * np.log2(r)})
     m = t.model
-    got = m.predict("pallas", nmodes=3, rank=32, blk=32, tile_rows=8)
+    got = m.predict("pallas_fused", nmodes=3, rank=32, blk=32, tile_rows=8)
     assert got == pytest.approx(0.01 * 5.0)           # log2(32) = 5
-    # crossover: pallas wins below log2(r)=4, ref above
-    assert t.best_backend(nmodes=3, rank=16, blk=32, tile_rows=8) == "pallas"
+    # crossover: pallas_fused wins below log2(r)=4, ref above
+    assert t.best_backend(nmodes=3, rank=16, blk=32,
+                          tile_rows=8) == "pallas_fused"
     assert t.best_backend(nmodes=3, rank=64, blk=32, tile_rows=8) == "ref"
     # clamped extrapolation beyond the knots
-    assert m.predict("pallas", nmodes=3, rank=1024, blk=32,
+    assert m.predict("pallas_fused", nmodes=3, rank=1024, blk=32,
                      tile_rows=8) == pytest.approx(0.01 * 6.0)
 
 
 def test_off_grid_shape_resolves_to_nearest_group():
-    t = _table_with_ranks((16,), lambda r: {"pallas": 0.5, "ref": 0.1})
+    t = _table_with_ranks((16,), lambda r: {"pallas_fused": 0.5, "ref": 0.1})
     # different (blk, tile_rows) than any entry: nearest group answers
     assert t.best_backend(nmodes=3, rank=16, blk=512, tile_rows=128) == "ref"
     # different nmodes too
@@ -191,15 +193,15 @@ def test_select_backend_without_table_is_static(table):
                 if rank < kops.MIN_MXU_RANK:
                     want = "ref"
                 elif kkernel.fused_vmem_bytes(
-                        nmodes - 1, rpad, blk, 128) <= \
+                        KRP_OPERANDS, rpad, blk, 128) <= \
                         kops.VMEM_BUDGET_BYTES:
                     want = "pallas_fused"
                 elif kkernel.fused_tiled_vmem_bytes(
-                        nmodes - 1, rpad, blk, 128) <= \
+                        KRP_OPERANDS, rpad, blk, 128) <= \
                         kops.VMEM_BUDGET_BYTES:
                     want = "pallas_fused_tiled"
                 else:
-                    want = "pallas"
+                    want = "ref"
                 assert static == want
                 assert kops.select_backend(
                     "auto", table=empty, **kw) == static
@@ -232,10 +234,10 @@ def test_below_grid_rank_keeps_static_mxu_guard(table):
     # ...but a rank the table actually measured answers from measurements
     low = CalibrationTable(entries=[tune.CalibrationEntry(
         nmodes=3, rank=4, blk=32, tile_rows=8, density=1.0,
-        timings_s={"pallas": 0.001, "ref": 0.5})])
+        timings_s={"pallas_fused": 0.001, "ref": 0.5})])
     assert low.covers(nmodes=3, rank=4, blk=32, tile_rows=8)
     assert kops.select_backend("auto", nmodes=3, rank=4, blk=32,
-                               tile_rows=8, table=low) == "pallas"
+                               tile_rows=8, table=low) == "pallas_fused"
     # plan_modes applies the same guard
     _, ft = _small_ft()
     plans = tune.plan_modes(table, ft, 4)
@@ -251,25 +253,53 @@ def test_table_cannot_pick_infeasible_fused():
     working set always fits — the PR-2 rule fell all the way back to
     the materialized path here.)"""
     t = _table_with_ranks(
-        (16, 256), lambda r: {"pallas_fused": 0.001, "pallas": 1.0,
-                              "ref": 1.0})
-    kw = dict(nmodes=5, rank=8192, blk=512, tile_rows=128)
+        (16, 256), lambda r: {"pallas_fused": 0.001, "ref": 1.0})
+    kw = dict(nmodes=5, rank=16384, blk=512, tile_rows=128)
     assert kkernel.fused_vmem_bytes(
-        4, kops.padded_rank(8192), 512, 128) > kops.VMEM_BUDGET_BYTES
+        KRP_OPERANDS, kops.padded_rank(16384), 512,
+        128) > kops.VMEM_BUDGET_BYTES
     got = kops.select_backend("auto", table=t, **kw)
     assert got == kops.select_backend("auto", **kw) == "pallas_fused_tiled"
     # ...and plan_modes applies the same guard per candidate shape
     entries = [tune.CalibrationEntry(nmodes=3, rank=r, blk=512,
                                      tile_rows=128, density=1.0,
                                      timings_s={"pallas_fused": 0.001,
-                                                "pallas": 1.0})
+                                                "ref": 1.0})
                for r in (16, 256)]
     _, ft = _small_ft()          # 3-mode: fused needs rank 16384 to overflow
     assert kkernel.fused_vmem_bytes(
-        2, kops.padded_rank(16384), 512, 128) > kops.VMEM_BUDGET_BYTES
+        KRP_OPERANDS, kops.padded_rank(16384), 512,
+        128) > kops.VMEM_BUDGET_BYTES
     plans = tune.plan_modes(CalibrationTable(entries=entries), ft, 16384)
     assert plans is not None
     assert all(p.backend != "pallas_fused" for p in plans)
+
+
+def test_table_cannot_pick_removed_pallas_rung():
+    """Tables written before the ``pallas`` backend was folded into
+    ``pallas_fused`` may still time it. A table that loves it at a rank
+    where the untiled operand block overflows VMEM must land on the
+    static decision, as one that loves ``pallas_fused`` does."""
+    t = _table_with_ranks(
+        (16, 256), lambda r: {"pallas": 0.0005, "pallas_fused": 0.001,
+                              "ref": 1.0})
+    assert "pallas" not in kops.BACKENDS
+    kw = dict(nmodes=5, rank=16384, blk=512, tile_rows=128)
+    assert t.best_backend(**kw) == "pallas"     # the raw table argmin
+    got = kops.select_backend("auto", table=t, **kw)
+    assert got == kops.select_backend("auto", **kw) == "pallas_fused_tiled"
+    with pytest.raises(ValueError, match="unknown MTTKRP backend"):
+        kops.select_backend("pallas", nmodes=5, rank=16)
+    entries = [tune.CalibrationEntry(nmodes=3, rank=r, blk=512,
+                                     tile_rows=128, density=1.0,
+                                     timings_s={"pallas": 0.0005,
+                                                "pallas_fused": 0.001})
+               for r in (16, 256)]
+    _, ft = _small_ft()
+    # Neither measured backend may be planned, so the table cannot
+    # answer and the runtime keeps its static configuration.
+    assert tune.plan_modes(CalibrationTable(entries=entries), ft,
+                           16384) is None
 
 
 # ---------------------------------------------------------------------------
@@ -307,7 +337,8 @@ def test_runtime_back_compat_construction():
         num_workers=1, nmodes=3, rank=8, rows_cap=(8, 8, 8),
         i_pad=(8, 8, 8), nnz_cap=8, bucket_cap=8, shape=(8, 8, 8))
     assert rt.bucket_cap_for(2) == 8
-    assert rt.plan_for(1, "pallas") == dist.ModePlan("pallas", 512, 128)
+    assert rt.plan_for(1, "pallas_fused") == dist.ModePlan("pallas_fused",
+                                                          512, 128)
 
 
 def test_prepare_runtime_with_table_builds_plans(table):
