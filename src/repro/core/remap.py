@@ -11,7 +11,9 @@ remapping" (Fig. 2). Storage stays ``2·|T|`` (send + receive buffers), never
 
 All shapes are static: bucket capacity is the preprocessing-time max bucket
 size (like MoE capacity), padding is masked, and every element is accounted
-for (the round-trip property is tested).
+for (the round-trip property is tested). Each bucket is a contiguous window
+of the destination-sorted stream, filled by one row gather: the bucketing
+holds no scatter and no dump row.
 """
 from __future__ import annotations
 
@@ -62,7 +64,7 @@ def remap_capacity(ft: FlycooTensor) -> int:
 
 
 def bucket_by_destination(dest, payload, num_devices: int, bucket_cap: int):
-    """Scatter ``payload`` rows into per-destination buckets (static shape).
+    """Gather ``payload`` rows into per-destination buckets (static shape).
 
     Args:
       dest: ``(n,)`` int32 destination worker per element; ``>= num_devices``
@@ -78,36 +80,35 @@ def bucket_by_destination(dest, payload, num_devices: int, bucket_cap: int):
       ``bucket_cap >= remap_capacity``; exposed for the fault-tolerance
       check).
 
-    Elements keep their stream order inside a bucket (stable sort). Each
-    bucket's run in the sorted stream starts at the sum of the
-    per-destination counts below it, so no search over the stream is made.
+    Bucket ``d`` is one contiguous window of the destination-sorted stream:
+    the stable sort puts its elements, in stream order, at positions
+    ``[start_d, start_d + count_d)``, where ``start_d`` is the sum of the
+    per-destination counts below ``d``. Each bucket is filled by gathering
+    the payload rows of its window's first ``min(count_d, B)`` positions, so
+    no scatter, no dump row and no search over the stream is made. Rows
+    past a bucket's count are zero and masked; padding elements
+    (``dest >= D``) sort after every bucket and land in none.
     """
-    n = dest.shape[0]
     order = jnp.argsort(dest, stable=True)
-    dest_s = jnp.take(dest, order)
-    payload_s = jnp.take(payload, order, axis=0)
-    # Position of each element inside its destination bucket: its sorted
-    # position minus its bucket's start, the count of elements bound for
-    # lower destinations. D is small and static, so the counts are D
-    # reductions and the start an elementwise sum over them.
-    count = [jnp.sum(dest == d, dtype=dest.dtype) for d in range(num_devices)]
-    start = sum(jnp.where(dest_s > d, count[d], 0)
-                for d in range(num_devices))
-    pos = jnp.arange(n, dtype=dest.dtype) - start
-    ok = (dest_s < num_devices) & (pos < bucket_cap)
-    slot = jnp.where(ok, dest_s * bucket_cap + pos, num_devices * bucket_cap)
-    flat = jnp.zeros(
-        (num_devices * bucket_cap + 1, payload.shape[1]), dtype=payload.dtype
-    ).at[slot].set(payload_s)
-    maskf = jnp.zeros((num_devices * bucket_cap + 1,), dtype=jnp.bool_)\
-        .at[slot].set(ok)
-    valid = dest_s < num_devices
-    dropped = jnp.sum(valid & ~ok)
-    return (
-        flat[:-1].reshape(num_devices, bucket_cap, payload.shape[1]),
-        maskf[:-1].reshape(num_devices, bucket_cap),
-        dropped,
-    )
+    # D is small and static: the counts are D reductions over the stream,
+    # the starts their exclusive cumulative sum.
+    count = jnp.stack([jnp.sum(dest == d, dtype=dest.dtype)
+                       for d in range(num_devices)])
+    start = jnp.cumsum(count) - count
+    # ``bucket_cap`` filler entries keep a window that runs past the end
+    # of the stream where it starts (``dynamic_slice`` would clamp it).
+    order_padded = jnp.concatenate(
+        [order, jnp.zeros((bucket_cap,), order.dtype)])
+    window = jnp.concatenate([
+        jax.lax.dynamic_slice(order_padded, (start[d],), (bucket_cap,))
+        for d in range(num_devices)
+    ])
+    rows = jnp.take(payload, window, axis=0).reshape(
+        num_devices, bucket_cap, payload.shape[1])
+    mask = (jnp.arange(bucket_cap, dtype=count.dtype)[None, :]
+            < jnp.minimum(count, bucket_cap)[:, None])
+    dropped = jnp.sum(jnp.maximum(count - bucket_cap, 0))
+    return jnp.where(mask[..., None], rows, 0), mask, dropped
 
 
 def exchange(buckets, bucket_mask, axis_name: str):

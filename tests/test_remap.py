@@ -1,4 +1,6 @@
 """Dynamic tensor remapping (paper §III-B): round-trip + capacity bounds."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -134,30 +136,69 @@ def test_bucket_by_destination_matches_oracle(seed, num_dev, n, fill):
     _assert_buckets_match_oracle(dest, payload, num_dev, cap)
 
 
-@pytest.mark.parametrize("overflow", [False, True],
-                         ids=["fits", "overflow"])
-@pytest.mark.parametrize("num_dev", [1, 2, 3, 4, 5, 6])
-def test_bucket_by_destination_matches_oracle_per_device_count(num_dev,
-                                                               overflow):
+def _skewed(num_dev, overflow):
+    """Skewed toward device 0, with ``dest == D`` padding trailing and
+    mixed in; the capacity fits the largest bucket or half of it."""
     rng = np.random.default_rng(num_dev)
     n = 120
-    # skewed toward device 0, with ``dest == D`` padding trailing and mixed in
     dest = np.minimum(rng.geometric(0.4, n) - 1, num_dev).astype(np.int32)
     dest[-10:] = num_dev
     payload = np.stack([np.arange(n), rng.integers(-9, 9, n)], 1)\
         .astype(np.int32)
     most = int(np.bincount(dest[dest < num_dev]).max())
     cap = max(1, most // 2) if overflow else most
-    _assert_buckets_match_oracle(dest, payload, num_dev, cap)
+    return dest, payload, num_dev, cap
+
+
+def _int_payload(dest, seed=7):
+    n = dest.shape[0]
+    rng = np.random.default_rng(seed)
+    return np.stack([np.arange(n), rng.integers(-9, 9, n)], 1)\
+        .astype(np.int32)
+
+
+def _empty_between_full():
+    """D = 4: bucket 1 empty between full buckets 0 and 2, padding mixed in."""
+    rng = np.random.default_rng(7)
+    dest = rng.permutation(np.repeat(np.int32([0, 2, 3, 4]), [12, 12, 5, 7]))
+    return dest, _int_payload(dest), 4, 12
+
+
+def _all_padding():
+    """Every element is padding (``dest == D``): every bucket is empty."""
+    dest = np.full(40, 3, np.int32)
+    return dest, _int_payload(dest), 3, 8
+
+
+def _window_past_stream_end():
+    """D = 1 with ``cap > n``: the only window runs past the end of the
+    stream, so only the appended filler makes it a whole slice."""
+    dest = np.random.default_rng(7).integers(0, 2, 30).astype(np.int32)
+    dest[:3] = 1                          # padding ahead of the bucket's run
+    return dest, _int_payload(dest), 1, 45
+
+
+@pytest.mark.parametrize("case", [
+    *(pytest.param(functools.partial(_skewed, num_dev, overflow),
+                   id=f"{num_dev}-{'overflow' if overflow else 'fits'}")
+      for num_dev in [1, 2, 3, 4, 5, 6] for overflow in [False, True]),
+    pytest.param(_empty_between_full, id="empty-between-full"),
+    pytest.param(_all_padding, id="all-padding"),
+    pytest.param(_window_past_stream_end, id="cap-past-stream-end"),
+])
+def test_bucket_by_destination_matches_oracle_per_device_count(case):
+    _assert_buckets_match_oracle(*case())
 
 
 @pytest.mark.parametrize("num_dev", [1, 4])
-def test_bucket_by_destination_lowers_no_loop(num_dev):
-    """Bucket starts come from counts: the lowered bucketing holds no
-    ``while``, so no binary search over the stream."""
+def test_bucket_by_destination_lowers_no_loop_or_scatter(num_dev):
+    """Bucket starts come from counts and buckets are gathered windows of
+    the sorted stream: the lowered bucketing holds no ``while``, so no
+    binary search over the stream, and no ``scatter``."""
     n = 1000
     text = jax.jit(
         lambda d, p: remap_lib.bucket_by_destination(d, p, num_dev, 300)
     ).lower(jax.ShapeDtypeStruct((n,), jnp.int32),
             jax.ShapeDtypeStruct((n, 3), jnp.float32)).as_text()
     assert "stablehlo.while" not in text
+    assert "stablehlo.scatter" not in text
